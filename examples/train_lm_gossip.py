@@ -21,8 +21,7 @@ if "XLA_FLAGS" not in os.environ:
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro import configs
 from repro.data.lm_data import token_batches
@@ -42,7 +41,7 @@ def main():
     cfg = configs.get_config("xlstm-125m", args.variant,
                              scan_layers=False, remat=False)
     pods = len(jax.devices())
-    mesh = jax.make_mesh((pods,), ("pod",))
+    mesh = jax.make_mesh((pods,), ("pod",), (AxisType.Auto,))
     opt = Adam(lr=3e-4, clip_norm=1.0)
 
     n_params = None
@@ -63,10 +62,10 @@ def main():
                 st = jax.tree.map(lambda t: t[0], state_blk)
                 st, metrics = inner(st, batch_blk)
                 return jax.tree.map(lambda t: t[None], st), metrics
-            step = jax.jit(shard_map(per_pod, mesh=mesh,
-                                     in_specs=(P("pod"), P("pod")),
-                                     out_specs=(P("pod"), P("pod")),
-                                     check_rep=False))
+            step = jax.jit(jax.shard_map(per_pod, mesh=mesh,
+                                         in_specs=(P("pod"), P("pod")),
+                                         out_specs=(P("pod"), P("pod")),
+                                         check_vma=False))
             state = jax.tree.map(
                 lambda t: jnp.broadcast_to(t, (pods,) + t.shape).copy(), state)
         else:
@@ -76,10 +75,10 @@ def main():
                 st, metrics = inner(st, batch_blk)
                 st = st._replace(params=gossip.all_average(st.params, "pod"))
                 return jax.tree.map(lambda t: t[None], st), metrics
-            step = jax.jit(shard_map(allreduce_pod, mesh=mesh,
-                                     in_specs=(P("pod"), P("pod")),
-                                     out_specs=(P("pod"), P("pod")),
-                                     check_rep=False))
+            step = jax.jit(jax.shard_map(allreduce_pod, mesh=mesh,
+                                         in_specs=(P("pod"), P("pod")),
+                                         out_specs=(P("pod"), P("pod")),
+                                         check_vma=False))
             state = jax.tree.map(
                 lambda t: jnp.broadcast_to(t, (pods,) + t.shape).copy(), state)
 

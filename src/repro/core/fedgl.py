@@ -28,7 +28,10 @@ optimizer states) is likewise stacked on a leading ``[N]`` axis — there are no
 Python lists of per-server pytrees — and the whole imputation round is a
 single ``jax.vmap`` over that axis. When an edge mesh is supplied
 (``make_edge_mesh`` in ``launch/mesh.py``) the ``[N]`` axis is placed on a
-JAX device mesh and the vmapped round shards across devices. Everything jits;
+JAX device mesh, and every per-client and per-server map (:meth:`FGLTrainer.
+vmap`) runs under ``shard_map``: each device maps its own block of servers
+and their clients, so a Pallas kernel inside the map sees one device's
+block (Mosaic kernels cannot be partitioned by the compiler). Everything jits;
 the outer edge-client communication loop is a Python loop (it mutates graph
 structure on imputation rounds).
 """
@@ -105,6 +108,9 @@ class FGLTrainer:
         if cfg.kernel_impl not in imputation_lib.KERNEL_IMPLS:
             raise ValueError(f"unknown kernel_impl {cfg.kernel_impl!r}; "
                              f"expected one of {imputation_lib.KERNEL_IMPLS}")
+        if cfg.gnn_kind == "gat" and cfg.kernel_impl != "reference":
+            raise ValueError(f"gnn_kind 'gat' has no Pallas kernel; "
+                             f"kernel_impl {cfg.kernel_impl!r} would not run")
         if not 0.0 < cfg.participation <= 1.0:
             raise ValueError(f"participation must be in (0, 1], "
                              f"got {cfg.participation}")
@@ -185,6 +191,29 @@ class FGLTrainer:
                         as_params=as_params, as_opt=as_opt,
                         batch=batch, key=k_run)
 
+    def vmap(self, fn, in_axes=0):
+        """``jax.vmap`` over a leading client or server axis.
+
+        On an edge mesh of more than one device the map runs under
+        ``shard_map``: each device maps its contiguous block of the axis
+        (clients are grouped per server, so client and server blocks line
+        up). ``in_axes`` entries are 0 (mapped, split across the mesh) or
+        None (broadcast, replicated).
+        """
+        mapped = jax.vmap(fn, in_axes=in_axes)
+        mesh = self.edge_mesh
+        if mesh is None or mesh.size == 1:
+            return mapped
+        from jax.sharding import PartitionSpec as P
+        block = P(mesh.axis_names[0])
+
+        def call(*args):
+            axes = in_axes if isinstance(in_axes, tuple) else (in_axes,) * len(args)
+            specs = tuple(P() if a is None else block for a in axes)
+            return jax.shard_map(mapped, mesh=mesh, in_specs=specs,
+                                 out_specs=block, check_vma=False)(*args)
+        return call
+
     def _shard_edge(self, tree: PyTree) -> PyTree:
         """Place the leading [N] server axis of stacked state on the edge mesh."""
         if self.edge_mesh is None:
@@ -204,8 +233,8 @@ class FGLTrainer:
             if self.is_spread and self.cfg.trace_reg > 0:
                 loss = loss + self.cfg.trace_reg * _trace_reg(params)
             return loss
-        losses = jax.vmap(one)(params_m, batch.x, batch.adj, batch.y,
-                               batch.node_mask, batch.train_mask)
+        losses = self.vmap(one)(params_m, batch.x, batch.adj, batch.y,
+                                batch.node_mask, batch.train_mask)
         return jnp.sum(losses)  # sum => per-client grads stay independent
 
     def _local_rounds(self, params, opt_state, batch: ClientBatch):
@@ -292,7 +321,7 @@ class FGLTrainer:
             logits = gnn.apply_classifier(p, self.cfg.gnn_kind, x, adj, mask,
                                           impl=self.kernel_impl)
             return jax.nn.softmax(logits, axis=-1)
-        return jax.vmap(one)(params, batch.x, batch.adj, batch.node_mask)
+        return self.vmap(one)(params, batch.x, batch.adj, batch.node_mask)
 
     def _train_generator(self, key, ae, ae_opt, asr, as_opt, h_real, flat_mask):
         """Alternating AE / assessor training (Algorithm 1 lines 16-23).
@@ -411,7 +440,7 @@ class FGLTrainer:
             fp = jnp.sum(onehot_p * (1 - onehot_y), axis=0)
             fn = jnp.sum((1 - onehot_p) * onehot_y, axis=0)
             return correct, jnp.sum(mask), tp, fp, fn
-        correct, total, tp, fp, fn = jax.vmap(one)(
+        correct, total, tp, fp, fn = self.vmap(one)(
             params, batch.x, batch.adj, batch.y, batch.node_mask, batch.test_mask)
         acc = jnp.sum(correct) / jnp.maximum(jnp.sum(total), 1.0)
         tp, fp, fn = jnp.sum(tp, 0), jnp.sum(fp, 0), jnp.sum(fn, 0)

@@ -35,20 +35,9 @@ import jax.numpy as jnp
 PyTree = Any
 
 
-def _axis_size(axis: str) -> int:
-    """Static size of a named mesh axis.
-
-    ``jax.lax.axis_size`` only exists on newer jax; ``psum(1, axis)`` is the
-    classic idiom and constant-folds to a Python int on every version.
-    """
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis)
-    return jax.lax.psum(1, axis)
-
-
 def ring_gossip(params: PyTree, axis: str) -> PyTree:
     """Eq. 16 with a ring adjacency (self + both neighbors, equal weights)."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
     if n == 1:
         return params
     perm_fwd = [(i, (i + 1) % n) for i in range(n)]
@@ -65,7 +54,7 @@ def ring_gossip(params: PyTree, axis: str) -> PyTree:
 
 def all_average(params: PyTree, axis: str) -> PyTree:
     """Classic FedAvg analogue: full average over the axis (all-reduce)."""
-    n = _axis_size(axis)
+    n = jax.lax.axis_size(axis)
 
     def avg(p):
         return (jax.lax.psum(p.astype(jnp.float32), axis) / n).astype(p.dtype)
@@ -116,7 +105,7 @@ def block_ring_gossip(params: PyTree, axis: Optional[str] = None) -> PyTree:
             left = jnp.roll(f32, 1, axis=0)
             right = jnp.roll(f32, -1, axis=0)
         else:
-            size = _axis_size(axis)
+            size = jax.lax.axis_size(axis)
             if size * n_block == 1:
                 return p
             fwd = [(i, (i + 1) % size) for i in range(size)]

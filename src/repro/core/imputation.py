@@ -73,9 +73,10 @@ def similarity_topk(h: jnp.ndarray, flat_mask: jnp.ndarray, client_ids: jnp.ndar
     - ``mesh is not None``: the candidate-sharded ring driver
       (core/ring_topk.py) — candidate slabs rotate around the mesh ring via
       collective_permute and each device streams them into its partial top-k,
-      which after ``mesh.size`` steps IS the exact global answer (bit-
-      identical to ``"reference"``). ``h``/masks may carry a leading batch
-      axis here (one element per edge server), which rides along replicated.
+      which after ``mesh.size`` steps is the global answer: it agrees with
+      ``"reference"`` up to f32 rounding, as ``ring_topk.topk_violations``
+      states. ``h``/masks may carry a leading batch axis here (one element
+      per edge server), which rides along replicated.
 
     ``flat_mask`` marks valid *source* rows; ``target_mask`` (defaults to
     ``flat_mask``) marks slots allowed as link targets — the engine restricts

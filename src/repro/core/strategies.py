@@ -334,7 +334,6 @@ class GossipAggregator:
 
         use_ring = self.topology == "ring" and num_servers >= 3
         if self.mesh is not None and self.mesh.size > 1:
-            from jax.experimental.shard_map import shard_map
             from jax.sharding import PartitionSpec as P
             axis = self.mesh.axis_names[0]
 
@@ -343,8 +342,8 @@ class GossipAggregator:
                     return gossip.block_ring_gossip(blk, axis)
                 return gossip.adjacency_gossip(blk, adj, axis)
 
-            return shard_map(ex, mesh=self.mesh, in_specs=(P(axis),),
-                             out_specs=P(axis), check_rep=False)(w)
+            return jax.shard_map(ex, mesh=self.mesh, in_specs=(P(axis),),
+                                 out_specs=P(axis), check_vma=False)(w)
         if use_ring:
             return gossip.block_ring_gossip(w)
         return gossip.adjacency_gossip(w, adj)
@@ -612,8 +611,8 @@ class SpreadImputation:
     batched over the [N] axis, through the candidate-sharded ring driver
     (:mod:`repro.core.ring_topk`): each mesh device owns an [n/size] slice of
     every server's candidate axis and slabs rotate via collective_permute.
-    The ring result is bit-identical to the in-vmap reference, so the two
-    layouts are interchangeable (pinned in ``tests/test_ring_topk.py``).
+    The ring result agrees with the in-vmap search up to f32 rounding
+    (``ring_topk.topk_violations``; pinned in ``tests/test_ring_topk.py``).
     """
 
     sim_mesh: Any = None          # optional jax Mesh to shard candidates over
@@ -637,7 +636,7 @@ class SpreadImputation:
         key, server_keys = keys[0], keys[1:]
         client_ids = imputation.client_of_flat(mp, n_pad)
         if self.sim_mesh is None:
-            outs = jax.vmap(
+            outs = engine.vmap(
                 engine._server_round, in_axes=(0, 0, 0, 0, 0, 0, 0, None)
             )(server_keys, state.ae_params, state.ae_opt, state.as_params,
               state.as_opt, emb_g, mask_g, client_ids)
@@ -647,7 +646,7 @@ class SpreadImputation:
         # is the outermost transform (vmap-inside-shard_map composes; the
         # reverse does not). Numerically identical: the generator consumes
         # all the round's randomness, similarity is deterministic in h_flat.
-        (ae, aeo, asr, aso, x_bar, h_all, fmask_all) = jax.vmap(
+        (ae, aeo, asr, aso, x_bar, h_all, fmask_all) = engine.vmap(
             engine._server_round_gen, in_axes=(0, 0, 0, 0, 0, 0, 0)
         )(server_keys, state.ae_params, state.ae_opt, state.as_params,
           state.as_opt, emb_g, mask_g)

@@ -53,7 +53,8 @@ def topk_merge(run_v: jnp.ndarray, run_i: jnp.ndarray, slab_v: jnp.ndarray,
     top_k's tie-break on the full row — by value, not by position, so the
     result is independent of the order slabs are folded in: this is the
     invariant that lets per-shard partial top-ks over rotating candidate
-    slabs finish bit-identical to the single-device reference.
+    slabs finish with the single-device answer (up to the rounding of
+    their own gram tiles, see ``ring_topk.topk_violations``).
 
     Exhausted rows (best == -inf) select among stale popped entries and
     unfilled -1 slots; the emitted index is forced to -1 either way, so

@@ -40,8 +40,8 @@ the exchange routed through the mesh collectives).
 ``--sim-shard`` additionally rotates the imputation round's CANDIDATE axis
 around the same mesh as a ring (``core/ring_topk.py``): each device streams
 every other device's candidate slab through collective_permute and folds it
-into its running top-k — bit-identical results, 1/size candidate residency
-per device.
+into its running top-k — the single-device result up to f32 rounding
+(``ring_topk.topk_violations``), 1/size candidate residency per device.
 """
 import argparse
 import time
@@ -52,6 +52,7 @@ from repro.core.partition import partition_graph
 from repro.core.spreadfgl import make_spreadfgl, make_spreadfgl_gossip
 from repro.core.types import FGLConfig
 from repro.data.synthetic_graphs import DATASETS, make_sbm_graph
+from repro.launch import compile_cache
 from repro.launch.mesh import make_edge_mesh
 
 
@@ -68,10 +69,11 @@ def main() -> None:
                          "per-round Eq. 16 aggregation)")
     ap.add_argument("--sim-shard", action="store_true",
                     help="ring-rotate the imputation candidate axis around "
-                         "the mesh (core/ring_topk.py; bit-identical results)")
+                         "the mesh (core/ring_topk.py)")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    compile_cache.enable()
     mesh = make_edge_mesh(args.servers)
     print(f"[edge-mesh] {len(jax.devices())} device(s); mesh size {mesh.size} "
           f"for N={args.servers} edge servers")
@@ -83,7 +85,7 @@ def main() -> None:
     graph = make_sbm_graph(DATASETS[args.dataset], scale=0.15, seed=args.seed + 1,
                            feature_noise=3.0, signal_ratio=0.5)
     batch, _ = partition_graph(graph, args.clients, aug_max=12, seed=args.seed)
-    cfg = FGLConfig(hidden_dim=32, local_rounds=4, imputation_interval=2,
+    cfg = FGLConfig(local_rounds=4, imputation_interval=2,
                     top_k_links=4, aug_max=12,
                     gossip_every=max(args.gossip_every, 1))
     if args.gossip_every > 0:

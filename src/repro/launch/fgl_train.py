@@ -24,9 +24,9 @@ rounds instead of dense per-round Eq. 16 averaging; combine with
 ``--edge-mesh`` to place the exchange on the device mesh. ``--sim-shard``
 shards the CANDIDATE axis of the imputation similarity top-k across devices
 (candidate slabs ring-rotate via collective_permute, ``core/ring_topk.py``);
-the result is bit-identical to the single-device search, and when combined
-with ``--edge-mesh`` one mesh carries both the [N] server axis and the
-candidate ring.
+the result agrees with the single-device search up to f32 rounding
+(``ring_topk.topk_violations``), and when combined with ``--edge-mesh`` one
+mesh carries both the [N] server axis and the candidate ring.
 
 Heterogeneity axis (``docs/BENCHMARKS.md``): ``--partitioner`` picks the
 client-split strategy (``label_prop`` default, ``dirichlet`` label-skew
@@ -44,6 +44,11 @@ buffered instead of waiting for all M clients. The whole schedule is a pure
 function of (seed, round), so ``--resume`` reproduces it exactly, and
 ``--async-buffer M --delay-dist zero`` is bit-identical to synchronous
 FedAvg.
+
+``parse_args`` + ``build`` are the whole set-up (graph, partition, config,
+meshes, registry), so other drivers (``chip_smoke.py``) run exactly this
+path; ``main`` adds the fit, the report and the checkpoint files. The
+classifier's hidden width is ``FGLConfig``'s paper default (64).
 """
 from __future__ import annotations
 
@@ -60,9 +65,11 @@ from repro.core.partition import (PARTITIONERS, count_missing_links,
                                   partition_graph)
 from repro.core.types import FGLConfig
 from repro.data.synthetic_graphs import DATASETS, make_sbm_graph
+from repro.launch import compile_cache
 
 
-def main() -> None:
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate the CLI; resolves the method a flag implies."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--dataset", choices=tuple(DATASETS), default="cora")
     ap.add_argument("--method", default="SpreadFGL", choices=registry.names())
@@ -125,27 +132,10 @@ def main() -> None:
                          "similarity top-k across devices (ring rotation via "
                          "collective_permute, core/ring_topk.py); with "
                          "--edge-mesh the same mesh carries both axes")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    graph = make_sbm_graph(DATASETS[args.dataset], scale=args.scale,
-                           seed=args.seed + 1, feature_noise=args.feature_noise,
-                           signal_ratio=args.signal_ratio)
-    part = make_partitioner(args.partitioner, alpha=args.alpha)
-    batch, assign = partition_graph(graph, args.clients, aug_max=12,
-                                    seed=args.seed, label_ratio=args.label_ratio,
-                                    partitioner=part)
-    ent = label_skew_entropy(assign, graph.y, args.clients)
-    print(f"[fgl] {args.dataset}: {graph.num_nodes} nodes, "
-          f"{count_missing_links(graph, assign)} missing cross-client links")
-    print(f"[fgl] partitioner={args.partitioner} "
-          f"mean client label entropy={ent.mean():.3f} nats")
     if not 0.0 < args.participation <= 1.0:
         ap.error("--participation must be in (0, 1]")
-    if args.participation < 1.0:
-        n_part = max(1, math.ceil(args.participation * args.clients))
-        print(f"[fgl] partial participation: rho={args.participation} "
-              f"({n_part} of {args.clients} clients aggregate per round)")
-
     if args.gossip_every < 1:
         ap.error("--gossip-every must be >= 1 (1 == exchange every round)")
     if args.gossip_every > 1:
@@ -176,7 +166,35 @@ def main() -> None:
                      f"spreadfgl_async, not --method {args.method}")
     elif args.method == "spreadfgl_async":
         ap.error("--method spreadfgl_async needs --async-buffer >= 1")
-    cfg = FGLConfig(hidden_dim=32, local_rounds=args.local_rounds,
+    if args.sim_shard and args.method not in (
+            "FedGL", "SpreadFGL", "spreadfgl_gossip", "spreadfgl_async"):
+        ap.error(f"--sim-shard needs an imputation round to shard; "
+                 f"--method {args.method} has none")
+    return args
+
+
+def build(args: argparse.Namespace):
+    """Graph -> client partition -> config -> meshes -> registered trainer.
+
+    Returns ``(trainer, batch)``; prints what it built.
+    """
+    graph = make_sbm_graph(DATASETS[args.dataset], scale=args.scale,
+                           seed=args.seed + 1, feature_noise=args.feature_noise,
+                           signal_ratio=args.signal_ratio)
+    part = make_partitioner(args.partitioner, alpha=args.alpha)
+    batch, assign = partition_graph(graph, args.clients, aug_max=12,
+                                    seed=args.seed, label_ratio=args.label_ratio,
+                                    partitioner=part)
+    ent = label_skew_entropy(assign, graph.y, args.clients)
+    print(f"[fgl] {args.dataset}: {graph.num_nodes} nodes, "
+          f"{count_missing_links(graph, assign)} missing cross-client links")
+    print(f"[fgl] partitioner={args.partitioner} "
+          f"mean client label entropy={ent.mean():.3f} nats")
+    if args.participation < 1.0:
+        n_part = max(1, math.ceil(args.participation * args.clients))
+        print(f"[fgl] partial participation: rho={args.participation} "
+              f"({n_part} of {args.clients} clients aggregate per round)")
+    cfg = FGLConfig(local_rounds=args.local_rounds,
                     imputation_interval=args.imputation_interval,
                     top_k_links=args.top_k, aug_max=12,
                     label_ratio=args.label_ratio, kernel_impl=args.impl,
@@ -197,10 +215,6 @@ def main() -> None:
             print(f"[fgl] edge mesh: {kw['edge_mesh'].size} device(s) for "
                   f"N={args.servers}")
     if args.sim_shard:
-        if args.method not in ("FedGL", "SpreadFGL", "spreadfgl_gossip",
-                               "spreadfgl_async"):
-            ap.error(f"--sim-shard needs an imputation round to shard; "
-                     f"--method {args.method} has none")
         if "edge_mesh" in kw:
             # One mesh, two roles: the [N] server axis lives on it as data
             # placement, the candidate axis rotates around it as a ring —
@@ -209,8 +223,15 @@ def main() -> None:
         else:
             from repro.launch.mesh import make_sim_mesh
             kw["sim_mesh"] = make_sim_mesh()
+            if args.impl == "pallas" and kw["sim_mesh"].size > 1:
+                # The kernels run per device only under the edge mesh's
+                # shard_map (FGLTrainer.vmap); the compiler cannot
+                # partition them across a bare candidate ring.
+                raise ValueError("--sim-shard with --impl pallas on several "
+                                 "devices needs --edge-mesh")
         print(f"[fgl] sim shard: candidate axis over "
-              f"{kw['sim_mesh'].size} device(s)")
+              f"{kw['sim_mesh'].size} device(s); the ring folds its slabs "
+              f"with an XLA einsum, not the sim_topk kernel")
     if args.method == "spreadfgl_gossip":
         print(f"[fgl] gossip aggregation: cross-server exchange every "
               f"{args.gossip_every} round(s)")
@@ -218,8 +239,13 @@ def main() -> None:
         print(f"[fgl] async aggregation: buffer B={args.async_buffer} of "
               f"M={args.clients}, delays={args.delay_dist}, "
               f"dropout={args.dropout_rate}")
-    tr = registry.build(args.method, cfg, batch, **kw)
+    return registry.build(args.method, cfg, batch, **kw), batch
 
+
+def main() -> None:
+    args = parse_args()
+    compile_cache.enable()
+    tr, batch = build(args)
     if args.resume:
         state = ckpt_io.restore(args.resume,
                                 tr.init(jax.random.key(args.seed), batch))

@@ -27,7 +27,6 @@ import json
 import pathlib
 
 import jax
-from jax.experimental.shard_map import shard_map
 
 from repro import configs
 from repro.core import gossip
@@ -48,8 +47,8 @@ def lower_aggregation(cfg, mesh, mode: str):
             return gossip.ring_gossip(params, "pod")
         return gossip.all_average(params, "pod")
 
-    fn = shard_map(agg, mesh=mesh, in_specs=(pspecs,), out_specs=pspecs,
-                   check_rep=False)
+    fn = jax.shard_map(agg, mesh=mesh, in_specs=(pspecs,), out_specs=pspecs,
+                       check_vma=False)
     with jax.sharding.set_mesh(mesh):
         return jax.jit(fn).lower(params_specs).compile()
 

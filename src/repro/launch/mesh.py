@@ -6,17 +6,26 @@ carries SpreadFGL's edge-server topology (core/gossip.py).
 
 Functions, not module constants: importing this module never touches jax
 device state (dryrun.py must set XLA_FLAGS before the first jax call).
+
+Every mesh here has ``AxisType.Auto`` axes: the sharding rules
+(``sharding/rules.py``) and the shard_map bodies assume the compiler
+propagates shardings, not the explicit-sharding type system that
+``jax.make_mesh`` defaults to.
 """
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+
+def _auto_mesh(shape, axes) -> Mesh:
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_edge_mesh(num_servers: int, *, devices: int = 0) -> Mesh:
@@ -49,6 +58,6 @@ def make_host_mesh(*, model: int = 1, data: int = 0, pod: int = 0) -> Mesh:
     n = len(jax.devices())
     if pod:
         data = data or max(1, n // (model * pod))
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
+        return _auto_mesh((pod, data, model), ("pod", "data", "model"))
     data = data or max(1, n // model)
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))

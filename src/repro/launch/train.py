@@ -53,7 +53,6 @@ def main() -> None:
 
     if args.aggregation == "spread":
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         pods = args.pods or len(jax.devices())
         mesh = make_host_mesh(pod=pods, data=1, model=1)
         step_inner = make_train_step(cfg, opt, aggregation="spread",
@@ -66,10 +65,10 @@ def main() -> None:
             st, metrics = step_inner(st, batch_blk)
             return jax.tree.map(lambda t: t[None], st), metrics
 
-        step = jax.jit(shard_map(per_pod, mesh=mesh,
-                                 in_specs=(P("pod"), P("pod")),
-                                 out_specs=(P("pod"), P("pod")),
-                                 check_rep=False))
+        step = jax.jit(jax.shard_map(per_pod, mesh=mesh,
+                                     in_specs=(P("pod"), P("pod")),
+                                     out_specs=(P("pod"), P("pod")),
+                                     check_vma=False))
         # replicate the initial state across pods (they diverge between gossips)
         state = jax.tree.map(
             lambda t: jnp.broadcast_to(t, (pods,) + t.shape).copy(), state)

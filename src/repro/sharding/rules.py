@@ -43,7 +43,7 @@ DEFAULT_RULES: Dict[str, Optional[str]] = {
 }
 
 
-def _axis_size(mesh: Mesh, name) -> int:
+def _mesh_axis_size(mesh: Mesh, name) -> int:
     if name is None:
         return 1
     if isinstance(name, tuple):
@@ -78,7 +78,7 @@ def logical_to_spec(axes: Tuple, shape: Tuple[int, ...], mesh: Mesh,
         if any(t in used for t in target_t):
             entries.append(None)  # an axis can shard only one dim
             continue
-        if dim % _axis_size(mesh, target_t) != 0:
+        if dim % _mesh_axis_size(mesh, target_t) != 0:
             entries.append(None)  # divisibility fallback -> replicate
             continue
         used.update(target_t)

@@ -38,15 +38,16 @@ from repro.core.spreadfgl import make_spreadfgl_async
 M = 4  # clients in the `small` fixture
 
 # The pinned fixed-seed FedGL history of tests/test_strategy_api.py
-# (fit(key(0), rounds=4) on the `small` fixture). The async anchor must
-# reproduce the SAME run bit-for-bit, so it must also match this golden.
+# (fit(key(0), rounds=4) on the `small` fixture), pinned on jax 0.9.0 /
+# jaxlib 0.9.0. The async anchor must reproduce the SAME run bit-for-bit,
+# so it must also match this golden.
 GOLDEN_FEDGL = {
-    "loss": [1.5929425954818726, 0.27329501509666443,
-             0.07562695443630219, 0.03868856653571129],
-    "acc": [0.16363635659217834, 0.23636363446712494,
-            0.34545454382896423, 0.34545454382896423],
-    "f1": [0.09297052770853043, 0.18033909797668457,
-           0.2997002899646759, 0.3178369402885437],
+    "loss": [0.6813163161277771, 0.05321342498064041,
+             0.024075975641608238, 0.01454485859721899],
+    "acc": [0.38181817531585693, 0.581818163394928,
+            0.6000000238418579, 0.6545454263687134],
+    "f1": [0.3721662163734436, 0.5811243653297424,
+           0.5967587232589722, 0.6610444188117981],
 }
 
 
@@ -213,8 +214,10 @@ class TestSchedule:
         assert {agg.phase(t, 8) for t in range(40)} <= {0, 1}
 
     def test_different_seeds_give_different_schedules(self):
+        # Seeds 0 and 1 both flush on all 16 rounds under jax 0.9.0's
+        # streams, so the pair proves nothing; seeds 0 and 2 diverge.
         a = S.AsyncAggregator(buffer_size=2, delay_dist="geometric", seed=0)
-        b = S.AsyncAggregator(buffer_size=2, delay_dist="geometric", seed=1)
+        b = S.AsyncAggregator(buffer_size=2, delay_dist="geometric", seed=2)
         fa = [a.phase(t, 6) for t in range(16)]
         fb = [b.phase(t, 6) for t in range(16)]
         assert fa != fb

@@ -106,17 +106,17 @@ def test_gossip_preserves_mean_subprocess():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
         from repro.core import gossip
-        mesh = jax.make_mesh((8,), ("pod",))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(pod=8, data=1, model=1)
         x = jnp.arange(8.0 * 5).reshape(8, 5)
 
         def f(blk):
             out = gossip.ring_gossip({"w": blk[0]}, "pod")
             return out["w"][None]
 
-        y = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("pod"),),
-                              out_specs=P("pod"), check_rep=False))(x)
+        y = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(P("pod"),),
+                                  out_specs=P("pod"), check_vma=False))(x)
         np.testing.assert_allclose(np.asarray(y.mean(0)), np.asarray(x.mean(0)),
                                    rtol=1e-6)
         # each row is the average of itself and its ring neighbors
@@ -131,3 +131,24 @@ def test_gossip_preserves_mean_subprocess():
                          text=True, env=env, cwd=os.path.dirname(
                              os.path.dirname(os.path.abspath(__file__))))
     assert "GOSSIP-OK" in out.stdout, out.stderr[-2000:]
+
+
+@pytest.mark.parametrize("env_dir", [True, False], ids=["env", "checkout"])
+def test_compile_cache_location_subprocess(tmp_path, env_dir):
+    """Entry points cache compiles in $JAX_COMPILATION_CACHE_DIR when set,
+    else in the checkout's fixed ``.jax_cache``. A subprocess, so this test
+    process never turns the cache on; nothing is compiled."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = textwrap.dedent("""
+        import jax
+        from repro.launch import compile_cache
+        print("DIR", compile_cache.enable(), jax.config.jax_compilation_cache_dir)
+    """)
+    env = dict(os.environ, PYTHONPATH="src")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = os.path.join(root, ".jax_cache")
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                         text=True, env=env, cwd=root)
+    assert f"DIR {want} {want}" in out.stdout, out.stderr[-2000:]

@@ -64,20 +64,20 @@ class TestLogicalToSpec:
 
 
 @pytest.mark.slow
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "set_mesh"),
-    reason="jax.sharding.set_mesh landed after this jax version "
-           f"({jax.__version__}); the subprocess inherits the same jax")
 def test_multi_device_lowering_subprocess():
     """End-to-end spec plumbing on 8 forced host devices (subprocess so the
-    main test process keeps its single-device jax)."""
+    main test process keeps its single-device jax). The mesh comes from
+    ``launch/mesh.py``, whose Auto axes let the compiler reshard where the
+    rules put one mesh axis on two dims of an intermediate (the embedding
+    gather of a batch-sharded token array)."""
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax
         from repro.configs import get_config, INPUT_SHAPES, InputShape
         from repro.launch.dryrun import build_lowerable
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_host_mesh
+        mesh = make_host_mesh(model=4)
         cfg = get_config("qwen3-4b", "smoke")
         shape = InputShape("t", 64, 8, "train")
         fn, args = build_lowerable(cfg, shape, mesh)

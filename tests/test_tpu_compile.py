@@ -1,0 +1,93 @@
+"""Compile-only guards: the main path's Pallas kernels at real widths.
+
+Each test compiles a kernel for one chip of a described (not attached) TPU
+v5e and asserts the program holds the Mosaic kernel (``tpu_custom_call``).
+Nothing runs, so this proves only that the chip's compiler accepts the
+kernel at that shape: block alignment, VMEM limits, the lane-axis
+concatenate of ``topk_merge``. Interpret-mode tests cannot see any of that.
+
+Shapes are those of ``chip_smoke.py`` (client splits of the synthetic
+Table-I graphs at full size, label-propagation partition, 12 aug slots):
+
+- ``sim_topk``: one edge server's fused candidates, n = M_per * n_pad.
+  Cora with N=3, M=6 gives n_pad = 914, n = 1828, c = 7; N=4, M=8 gives
+  n_pad = 689, n = 1378; CoauthorCS with N=3, M=6 gives n_pad = 6123,
+  n = 12246, c = 15. n = 300 is a graph small enough that ``ops`` shrinks
+  the column block below 512 and off the 128-lane grid.
+- ``sage_aggregate``: one Cora client, adj [914, 914], at the input width
+  (1,433 features) and the hidden width (64), forward and gradient.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import ops
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    # A compile for a described chip can be written to the persistent cache
+    # but not read back without one; keep these tests out of it.
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _spec(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("n,c", [
+    (1828, 7),     # Cora, N=3, M=6
+    (1378, 7),     # Cora, N=4, M=8 (the four-chip run's single-chip side)
+    (12246, 15),   # CoauthorCS, N=3, M=6
+    (300, 7),      # small graph: column block of 300 lanes
+], ids=["cora", "cora_n4", "coauthor_cs", "small"])
+def test_sim_topk_compiles(one_chip, n, c):
+    # block_m=256 is what imputation.similarity_topk passes.
+    fn = jax.jit(lambda h, cid, mask: ops.sim_topk(h, cid, mask, 4,
+                                                   block_m=256))
+    compiled = fn.lower(_spec((n, c), jnp.float32, one_chip),
+                        _spec((n,), jnp.int32, one_chip),
+                        _spec((n,), jnp.float32, one_chip)).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("d", [1433, 64], ids=["input_width", "hidden_width"])
+def test_sage_aggregate_forward_and_grad_compile(one_chip, d):
+    n = 914
+
+    def loss(adj, h, g):
+        return jnp.sum(ops.sage_aggregate(adj, h) * g)
+
+    fn = jax.jit(jax.value_and_grad(loss, argnums=1))
+    compiled = fn.lower(_spec((n, n), jnp.float32, one_chip),
+                        _spec((n, d), jnp.float32, one_chip),
+                        _spec((n, d), jnp.float32, one_chip)).compile()
+    _assert_kernel(compiled)
