@@ -389,29 +389,23 @@ class FGLTrainer:
         (``SpreadImputation.sim_mesh``) can vmap this part over the [N]
         server axis and run ONE batched ring top-k outside the vmap —
         shard_map-over-vmap is the fragile composition, vmap-then-shard_map
-        is not. Returns the fused (h_flat, flat_mask) along with the trained
-        state so the caller computes ``target_mask`` and similarity from the
-        exact same fused embeddings.
+        is not. Returns the trained state, X̅, and the similarity search's
+        inputs (``imputation.search_inputs``: fused h, flat mask, client ids,
+        target mask) so the caller searches the exact same fused embeddings.
         """
-        h_flat, flat_mask = imputation.fuse_embeddings(emb_j, mask_j)
+        search = imputation.search_inputs(emb_j, mask_j, self.n_local)
         ae, aeo, asr, aso, s_noise = self._train_generator(
-            key_j, ae, aeo, asr, aso, h_flat, flat_mask)
+            key_j, ae, aeo, asr, aso, search[0], search[1])
         x_bar = imputation.encode(ae, s_noise)              # X̅ = f(S), same S
-        return ae, aeo, asr, aso, x_bar, h_flat, flat_mask
+        return ae, aeo, asr, aso, x_bar, search
 
-    def _server_round(self, key_j, ae, aeo, asr, aso, emb_j, mask_j, client_ids):
+    def _server_round(self, key_j, ae, aeo, asr, aso, emb_j, mask_j):
         """One edge server's imputation work on its [M_per, n_pad, c] slice."""
-        cfg = self.cfg
-        ae, aeo, asr, aso, x_bar, h_flat, flat_mask = self._server_round_gen(
-            key_j, ae, aeo, asr, aso, emb_j, mask_j)
-        # Link targets must be REAL local nodes: after the first fixing round
-        # the patcher sets node_mask=1 on aug slots, and without this
-        # restriction later rounds could link to synthetic nodes.
-        target_mask = flat_mask * imputation.local_slot_mask(
-            self.m_per, emb_j.shape[1], self.n_local)
+        ae, aeo, asr, aso, x_bar, (h, fmask, cid, tmask) = (
+            self._server_round_gen(key_j, ae, aeo, asr, aso, emb_j, mask_j))
         scores, idx = imputation.similarity_topk(
-            h_flat, flat_mask, client_ids, cfg.top_k_links,
-            kernel_impl=self.kernel_impl, target_mask=target_mask)
+            h, fmask, cid, self.cfg.top_k_links,
+            kernel_impl=self.kernel_impl, target_mask=tmask)
         return ae, aeo, asr, aso, scores, idx, x_bar
 
     def _imputation_round_reference(self, state: FGLState) -> FGLState:

@@ -137,6 +137,22 @@ def local_slot_mask(num_clients: int, n_pad: int, n_local: int) -> jnp.ndarray:
     return jnp.tile(local, num_clients)
 
 
+def search_inputs(client_h: jnp.ndarray, node_mask: jnp.ndarray, n_local: int
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """One edge server's similarity-search inputs from its clients' slices.
+
+    client_h [M, n_pad, c] and node_mask [M, n_pad] -> (h [M*n_pad, c],
+    flat_mask, client_ids, target_mask), the arguments of
+    :func:`similarity_topk`: Eq. 9 fusion, the owning client of each flat
+    slot, and link targets restricted to real local slots
+    (:func:`local_slot_mask`).
+    """
+    m, n_pad = node_mask.shape
+    h, flat_mask = fuse_embeddings(client_h, node_mask)
+    return (h, flat_mask, client_of_flat(m, n_pad),
+            flat_mask * local_slot_mask(m, n_pad, n_local))
+
+
 # ---------------------------------------------------------------------------
 # Eq. (10): autoencoder S -> X̅ = f(S) -> H̄ = h(X̅).
 # ---------------------------------------------------------------------------

@@ -72,7 +72,10 @@ def fold_slab(run_v: jnp.ndarray, run_i: jnp.ndarray,
     cross-subgraph valid targets and merged via :func:`topk_merge` with
     slab-local columns shifted by ``offset`` to global candidate indices.
     """
-    s = jnp.einsum("...qc,...nc->...qn", rows, cand)
+    # HIGHEST: f32 products, as in the sim_topk kernel; at default precision
+    # a TPU would round the inputs to bf16.
+    s = jnp.einsum("...qc,...nc->...qn", rows, cand,
+                   precision=jax.lax.Precision.HIGHEST)
     keep = ((row_cid[..., :, None] != cand_cid[..., None, :])
             & (cand_mask[..., None, :] > 0))
     s = jnp.where(keep, s, -jnp.inf)

@@ -619,6 +619,27 @@ class SpreadImputation:
 
     active = True
 
+    @staticmethod
+    def _per_server(engine, state):
+        """Client embeddings and node masks grouped per server:
+        ([N, M_per, n_pad, c], [N, M_per, n_pad])."""
+        emb = engine._embeddings(state.params, state.batch)  # [M, n_pad, c]
+        n, mp = engine.n_servers, engine.m_per
+        return (emb.reshape((n, mp) + emb.shape[1:]),
+                state.batch.node_mask.reshape((n, mp) + emb.shape[1:2]))
+
+    def search_inputs(self, engine, state):
+        """The [N]-stacked similarity-search inputs of ``state``'s round.
+
+        ``(h, flat_mask, client_ids, target_mask)`` per server
+        (``imputation.search_inputs``), built exactly as the round builds
+        them, so a caller can rerun or compare the search on the round's
+        own embeddings.
+        """
+        return engine.vmap(
+            lambda e, m: imputation.search_inputs(e, m, engine.n_local)
+        )(*self._per_server(engine, state))
+
     def server_outputs(self, engine, state):
         """The vmapped [N] generator round, before graph fixing.
 
@@ -626,36 +647,26 @@ class SpreadImputation:
         x_bar), key)`` with per-server leading [N] axes and the advanced
         round key — the raw link proposals the parity regressions inspect.
         """
-        batch = state.batch
-        emb = engine._embeddings(state.params, batch)       # [M, n_pad, c]
-        n_pad = batch.x.shape[1]
-        n, mp = engine.n_servers, engine.m_per
-        emb_g = emb.reshape((n, mp) + emb.shape[1:])        # [N, M_per, n_pad, c]
-        mask_g = batch.node_mask.reshape(n, mp, n_pad)
-        keys = jax.random.split(state.key, n + 1)
+        emb_g, mask_g = self._per_server(engine, state)
+        keys = jax.random.split(state.key, engine.n_servers + 1)
         key, server_keys = keys[0], keys[1:]
-        client_ids = imputation.client_of_flat(mp, n_pad)
         if self.sim_mesh is None:
-            outs = engine.vmap(
-                engine._server_round, in_axes=(0, 0, 0, 0, 0, 0, 0, None)
-            )(server_keys, state.ae_params, state.ae_opt, state.as_params,
-              state.as_opt, emb_g, mask_g, client_ids)
+            outs = engine.vmap(engine._server_round)(
+                server_keys, state.ae_params, state.ae_opt, state.as_params,
+                state.as_opt, emb_g, mask_g)
             return outs, key
         # Sharded path: vmap ONLY the generator half; the similarity runs
         # once over the stacked [N, n_flat, c] fused embeddings so shard_map
         # is the outermost transform (vmap-inside-shard_map composes; the
         # reverse does not). Numerically identical: the generator consumes
         # all the round's randomness, similarity is deterministic in h_flat.
-        (ae, aeo, asr, aso, x_bar, h_all, fmask_all) = engine.vmap(
-            engine._server_round_gen, in_axes=(0, 0, 0, 0, 0, 0, 0)
+        ae, aeo, asr, aso, x_bar, (h, fmask, cid, tmask) = engine.vmap(
+            engine._server_round_gen
         )(server_keys, state.ae_params, state.ae_opt, state.as_params,
           state.as_opt, emb_g, mask_g)
-        tmask_all = fmask_all * imputation.local_slot_mask(
-            mp, n_pad, engine.n_local)[None, :]
-        cid_all = jnp.broadcast_to(client_ids, fmask_all.shape)
         scores, idx = imputation.similarity_topk(
-            h_all, fmask_all, cid_all, engine.cfg.top_k_links,
-            kernel_impl=engine.kernel_impl, target_mask=tmask_all,
+            h, fmask, cid, engine.cfg.top_k_links,
+            kernel_impl=engine.kernel_impl, target_mask=tmask,
             mesh=self.sim_mesh)
         return (ae, aeo, asr, aso, scores, idx, x_bar), key
 
@@ -680,10 +691,8 @@ class SpreadImputation:
         """
         batch = state.batch
         emb = engine._embeddings(state.params, batch)       # [M, n_pad, c]
-        n_pad = batch.x.shape[1]
         keys = jax.random.split(state.key, engine.n_servers + 1)
         key, server_keys = keys[0], keys[1:]
-        client_ids = imputation.client_of_flat(engine.m_per, n_pad)
         outs = []
         for j in range(engine.n_servers):
             sl = slice(j * engine.m_per, (j + 1) * engine.m_per)
@@ -691,7 +700,7 @@ class SpreadImputation:
             outs.append(engine._server_round(
                 server_keys[j], take_j(state.ae_params), take_j(state.ae_opt),
                 take_j(state.as_params), take_j(state.as_opt), emb[sl],
-                batch.node_mask[sl], client_ids))
+                batch.node_mask[sl]))
         stack = lambda i: jax.tree.map(lambda *x: jnp.stack(x), *[o[i] for o in outs])
         ae_params, ae_opt, as_params, as_opt = (stack(i) for i in range(4))
         scores, idx, x_bar = patcher.stitch_server_links(

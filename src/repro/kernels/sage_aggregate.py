@@ -29,8 +29,10 @@ def _sage_kernel(a_ref, h_ref, o_ref, acc_scratch, deg_scratch):
 
     a = a_ref[...].astype(jnp.float32)   # [bm, bk]
     h = h_ref[...].astype(jnp.float32)   # [bk, bn]
+    # HIGHEST: f32 products whatever the caller's default matmul precision.
     acc_scratch[...] += jax.lax.dot_general(
-        a, h, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
+        a, h, (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
     deg_scratch[...] += jnp.sum(a, axis=-1, keepdims=True)
 
     @pl.when(ki == nk - 1)

@@ -92,7 +92,10 @@ def _sim_topk_kernel(rows_ref, h_ref, row_cid_ref, col_cid_ref, col_mask_ref,
 
     rows = rows_ref[...].astype(jnp.float32)            # [bm, c]
     h = h_ref[...].astype(jnp.float32)                  # [bn, c]
+    # HIGHEST: f32 products whatever the caller's default matmul precision,
+    # so scores round as in the ring driver's ``fold_slab``.
     s = jax.lax.dot_general(rows, h, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)  # [bm, bn]
 
     # Fused masking: cross-subgraph only + valid candidate targets only.

@@ -106,11 +106,8 @@ class TestAugSlotTargets:
         tr = make_spreadfgl(cfg, batch, num_servers=2)
         state = tr._impute_fn(tr.init(jax.random.key(0), batch))
         emb = tr._embeddings(state.params, state.batch)
-        n_pad = state.batch.n_pad
-        h_flat, flat_mask = imputation.fuse_embeddings(
-            emb[:tr.m_per], state.batch.node_mask[:tr.m_per])
-        tmask = flat_mask * imputation.local_slot_mask(tr.m_per, n_pad,
-                                                       tr.n_local)
+        _, flat_mask, _, tmask = imputation.search_inputs(
+            emb[:tr.m_per], state.batch.node_mask[:tr.m_per], tr.n_local)
         assert float(jnp.sum(flat_mask) - jnp.sum(tmask)) > 0  # aug slots real
 
 

@@ -12,8 +12,9 @@ The contract under test, layer by layer:
   sharding (2/4/8 emulated devices, non-divisible n, fully-masked rows,
   k > valid candidates, tie-breaks) meets ``ring_topk.topk_violations``'s
   contract (scores within ``dot_tolerance``, differing indices only at
-  near-ties) and runs in a subprocess so the device count can be forced
-  before jax initializes.
+  near-ties) and, on every case tested, returns the reference's indices
+  exactly, duplicated-row ties included; it runs in a subprocess so the
+  device count can be forced before jax initializes.
 - ``topk_violations`` itself accepts a near-tie swap and rejects a wrong
   candidate.
 - The engine's sharded layout (``SpreadImputation(sim_mesh=...)``: vmap the
@@ -226,6 +227,22 @@ class TestEngineShardedLayout:
                 err_msg=f"fixed batch .{name} diverged")
 
 
+    def test_search_inputs_are_the_rounds(self, small):
+        """``SpreadImputation.search_inputs`` rebuilds exactly what the
+        round searches: searching them gives the round's link proposals."""
+        batch, cfg = small
+        tr = make_spreadfgl(cfg, batch, num_servers=2)
+        state = tr.init(jax.random.key(0), batch)
+        (_, _, _, _, s_r, i_r, _), _ = tr.imputation.server_outputs(tr, state)
+        h, fmask, cid, tmask = tr.imputation.search_inputs(tr, state)
+        assert h.shape == (2, tr.m_per * batch.n_pad, h.shape[-1])
+        s, i = jax.vmap(lambda *a: imputation.similarity_topk(
+            a[0], a[1], a[2], cfg.top_k_links, target_mask=a[3]))(
+                h, fmask, cid, tmask)
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(i_r))
+        np.testing.assert_array_equal(np.asarray(s), np.asarray(s_r))
+
+
 class TestTrafficModel:
     def test_rotation_bytes_and_flops(self):
         n, c, size = 1024, 32, 4
@@ -321,6 +338,10 @@ def test_ring_parity_on_emulated_devices_subprocess():
                 rep = topk_violations(h, cid, mask, got_s, got_i, exp_s,
                                       exp_i, tol=dot_tolerance(h))
                 assert rep["violations"] == 0, (n, k, nd, rep)
+                # Stronger than the contract, and true of every case here
+                # (jax 0.9.0, CPU): the reference's indices, so the
+                # cross-shard smallest-index tie-break holds exactly.
+                assert rep["index_diffs"] == 0, (n, k, nd, rep)
         print("RING-TOPK-OK")
     """)
     env = dict(os.environ, PYTHONPATH="src")
