@@ -160,11 +160,8 @@ class FGLTrainer:
         # non-exchange rounds lower to zero cross-server collectives.
         # Unscheduled aggregators have period 1.
         self._agg_period = max(1, int(getattr(self.aggregator, "period", 1)))
-        self._agg_fn = jax.jit(functools.partial(
-            self.aggregator.aggregate, adj=self.adj_servers,
-            num_servers=self.n_servers, m_per=self.m_per),
-            static_argnames=("round",))
-        self._impute_fn = jax.jit(functools.partial(self.imputation.impute, self))
+        self._agg_fn = jax.jit(self._aggregate, static_argnames=("round",))
+        self._impute_fn = jax.jit(self._impute)
         self._eval_fn = jax.jit(self._evaluate)
 
     # -- initialization ------------------------------------------------------
@@ -243,11 +240,20 @@ class FGLTrainer:
             grads = jax.grad(self._client_loss)(params, batch)
             params, opt_state = self.opt.update(grads, opt_state, params)
             return (params, opt_state), ()
-        (params, opt_state), _ = jax.lax.scan(step, (params, opt_state), None,
-                                              length=self.cfg.local_rounds)
+        with jax.named_scope("local_train"):
+            (params, opt_state), _ = jax.lax.scan(
+                step, (params, opt_state), None, length=self.cfg.local_rounds)
         return params, opt_state
 
     # -- aggregation (strategy) ----------------------------------------------
+
+    def _aggregate(self, params, *, round=0, mask=None):
+        """The Aggregator's call, under the ``aggregate`` scope whatever the
+        strategy (FedAvg, Eq. 16, gossip, async)."""
+        with jax.named_scope("aggregate"):
+            return self.aggregator.aggregate(
+                params, adj=self.adj_servers, num_servers=self.n_servers,
+                m_per=self.m_per, round=round, mask=mask)
 
     def _agg_phase(self, t: int) -> int:
         """Canonical static phase for the jitted aggregation call.
@@ -393,20 +399,25 @@ class FGLTrainer:
         inputs (``imputation.search_inputs``: fused h, flat mask, client ids,
         target mask) so the caller searches the exact same fused embeddings.
         """
-        search = imputation.search_inputs(emb_j, mask_j, self.n_local)
-        ae, aeo, asr, aso, s_noise = self._train_generator(
-            key_j, ae, aeo, asr, aso, search[0], search[1])
-        x_bar = imputation.encode(ae, s_noise)              # X̅ = f(S), same S
+        with jax.named_scope("generator"):
+            search = imputation.search_inputs(emb_j, mask_j, self.n_local)
+            ae, aeo, asr, aso, s_noise = self._train_generator(
+                key_j, ae, aeo, asr, aso, search[0], search[1])
+            x_bar = imputation.encode(ae, s_noise)          # X̅ = f(S), same S
         return ae, aeo, asr, aso, x_bar, search
 
     def _server_round(self, key_j, ae, aeo, asr, aso, emb_j, mask_j):
         """One edge server's imputation work on its [M_per, n_pad, c] slice."""
         ae, aeo, asr, aso, x_bar, (h, fmask, cid, tmask) = (
             self._server_round_gen(key_j, ae, aeo, asr, aso, emb_j, mask_j))
-        scores, idx = imputation.similarity_topk(
-            h, fmask, cid, self.cfg.top_k_links,
-            kernel_impl=self.kernel_impl, target_mask=tmask)
+        with jax.named_scope("sim_topk"):
+            scores, idx = imputation.similarity_topk(
+                h, fmask, cid, self.cfg.top_k_links,
+                kernel_impl=self.kernel_impl, target_mask=tmask)
         return ae, aeo, asr, aso, scores, idx, x_bar
+
+    def _impute(self, state: FGLState) -> FGLState:
+        return self.imputation.impute(self, state)
 
     def _imputation_round_reference(self, state: FGLState) -> FGLState:
         """Sequential oracle of the vmapped generator round (tests/benchmarks).
@@ -434,17 +445,18 @@ class FGLTrainer:
             fp = jnp.sum(onehot_p * (1 - onehot_y), axis=0)
             fn = jnp.sum((1 - onehot_p) * onehot_y, axis=0)
             return correct, jnp.sum(mask), tp, fp, fn
-        correct, total, tp, fp, fn = self.vmap(one)(
-            params, batch.x, batch.adj, batch.y, batch.node_mask, batch.test_mask)
-        acc = jnp.sum(correct) / jnp.maximum(jnp.sum(total), 1.0)
-        tp, fp, fn = jnp.sum(tp, 0), jnp.sum(fp, 0), jnp.sum(fn, 0)
-        precision = tp / jnp.maximum(tp + fp, 1e-9)
-        recall = tp / jnp.maximum(tp + fn, 1e-9)
-        f1 = 2 * precision * recall / jnp.maximum(precision + recall, 1e-9)
-        seen = (tp + fn) > 0
-        macro_f1 = jnp.sum(jnp.where(seen, f1, 0.0)) / jnp.maximum(jnp.sum(seen), 1.0)
-        loss = self._client_loss(params, batch) / self.m
-        return loss, acc, macro_f1
+        with jax.named_scope("evaluate"):
+            correct, total, tp, fp, fn = self.vmap(one)(
+                params, batch.x, batch.adj, batch.y, batch.node_mask, batch.test_mask)
+            acc = jnp.sum(correct) / jnp.maximum(jnp.sum(total), 1.0)
+            tp, fp, fn = jnp.sum(tp, 0), jnp.sum(fp, 0), jnp.sum(fn, 0)
+            precision = tp / jnp.maximum(tp + fp, 1e-9)
+            recall = tp / jnp.maximum(tp + fn, 1e-9)
+            f1 = 2 * precision * recall / jnp.maximum(precision + recall, 1e-9)
+            seen = (tp + fn) > 0
+            macro_f1 = jnp.sum(jnp.where(seen, f1, 0.0)) / jnp.maximum(jnp.sum(seen), 1.0)
+            loss = self._client_loss(params, batch) / self.m
+            return loss, acc, macro_f1
 
     def evaluate(self, state: FGLState) -> Dict[str, jnp.ndarray]:
         """Metrics of the current state (device arrays, no host sync)."""
@@ -460,21 +472,35 @@ class FGLTrainer:
         round index hits the every-K schedule, aggregation, then evaluation.
         Returns a new state at ``round + 1`` and metrics as device arrays
         (``{"round", "loss", "acc", "f1"}``) — callers decide when to sync.
+
+        Under ``jax.profiler`` the round is a ``fgl.round`` step span with
+        one child span per dispatch (``fgl.local``, ``fgl.impute``,
+        ``fgl.aggregate``, ``fgl.evaluate``) and ``fgl.schedule`` around the
+        host's aggregation schedule, each carrying ``round=t``
+        (``docs/TRACING.md``). With no profiler active a span costs under a
+        microsecond.
         """
         t = int(state.round)
-        state = dataclasses.replace(state)   # never mutate the caller's state
-        state.params, state.opt_state = self._local_fn(
-            state.params, state.opt_state, state.batch)
-        if self.imputation.active and (t % self.cfg.imputation_interval == 0):
-            state = self._impute_fn(state)
-        # The gossip phase, the participation mask, and the async flush
-        # schedule are pure functions of the absolute round, so a state
-        # restored mid-interval (or mid-buffer) resumes every schedule
-        # exactly where the checkpoint left it.
-        state.params = self._agg_fn(state.params, round=self._agg_phase(t),
-                                    mask=self._agg_mask(t))
-        loss, acc, f1 = self._eval_fn(state.params, state.batch)
-        state.round = t + 1
+        span = functools.partial(jax.profiler.TraceAnnotation, round=t)
+        with jax.profiler.StepTraceAnnotation("fgl.round", step_num=t, round=t):
+            state = dataclasses.replace(state)   # never mutate the caller's state
+            with span("fgl.local"):
+                state.params, state.opt_state = self._local_fn(
+                    state.params, state.opt_state, state.batch)
+            if self.imputation.active and (t % self.cfg.imputation_interval == 0):
+                with span("fgl.impute"):
+                    state = self._impute_fn(state)
+            # The gossip phase, the participation mask, and the async flush
+            # schedule are pure functions of the absolute round, so a state
+            # restored mid-interval (or mid-buffer) resumes every schedule
+            # exactly where the checkpoint left it.
+            with span("fgl.schedule"):
+                phase, mask = self._agg_phase(t), self._agg_mask(t)
+            with span("fgl.aggregate"):
+                state.params = self._agg_fn(state.params, round=phase, mask=mask)
+            with span("fgl.evaluate"):
+                loss, acc, f1 = self._eval_fn(state.params, state.batch)
+            state.round = t + 1
         return state, {"round": t, "loss": loss, "acc": acc, "f1": f1}
 
     def fit(self, key: Optional[jax.Array] = None,

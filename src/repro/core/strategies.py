@@ -623,7 +623,8 @@ class SpreadImputation:
     def _per_server(engine, state):
         """Client embeddings and node masks grouped per server:
         ([N, M_per, n_pad, c], [N, M_per, n_pad])."""
-        emb = engine._embeddings(state.params, state.batch)  # [M, n_pad, c]
+        with jax.named_scope("generator"):  # the generator's inputs
+            emb = engine._embeddings(state.params, state.batch)  # [M, n_pad, c]
         n, mp = engine.n_servers, engine.m_per
         return (emb.reshape((n, mp) + emb.shape[1:]),
                 state.batch.node_mask.reshape((n, mp) + emb.shape[1:2]))
@@ -664,17 +665,19 @@ class SpreadImputation:
             engine._server_round_gen
         )(server_keys, state.ae_params, state.ae_opt, state.as_params,
           state.as_opt, emb_g, mask_g)
-        scores, idx = imputation.similarity_topk(
-            h, fmask, cid, engine.cfg.top_k_links,
-            kernel_impl=engine.kernel_impl, target_mask=tmask,
-            mesh=self.sim_mesh)
+        with jax.named_scope("sim_topk"):
+            scores, idx = imputation.similarity_topk(
+                h, fmask, cid, engine.cfg.top_k_links,
+                kernel_impl=engine.kernel_impl, target_mask=tmask,
+                mesh=self.sim_mesh)
         return (ae, aeo, asr, aso, scores, idx, x_bar), key
 
     def impute(self, engine, state):
         (ae_params, ae_opt, as_params, as_opt, scores, idx,
          x_bar), key = self.server_outputs(engine, state)
-        scores, idx, x_bar = patcher.stitch_server_links(scores, idx, x_bar)
-        batch = patcher.fix_graphs(state.batch, scores, idx, x_bar)
+        with jax.named_scope("patch"):
+            scores, idx, x_bar = patcher.stitch_server_links(scores, idx, x_bar)
+            batch = patcher.fix_graphs(state.batch, scores, idx, x_bar)
         return dataclasses.replace(state, batch=batch, ae_params=ae_params,
                                    ae_opt=ae_opt, as_params=as_params,
                                    as_opt=as_opt, key=key)

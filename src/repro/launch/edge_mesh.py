@@ -44,7 +44,6 @@ into its running top-k — the single-device result up to f32 rounding
 (``ring_topk.topk_violations``), 1/size candidate residency per device.
 """
 import argparse
-import time
 
 import jax
 
@@ -103,10 +102,8 @@ def main() -> None:
                  for d in leaf.devices()}
     print(f"[edge-mesh] stacked generator state spans device(s) {sorted(placement)}")
 
-    t0 = time.perf_counter()
     _, hist = tr.fit(jax.random.key(args.seed), batch, rounds=args.rounds)
-    dt = time.perf_counter() - t0
-    print(f"[edge-mesh] {args.rounds} rounds in {dt:.2f}s — "
+    print(f"[edge-mesh] {args.rounds} rounds — "
           f"best acc={max(hist['acc']):.3f} f1={max(hist['f1']):.3f}")
 
 

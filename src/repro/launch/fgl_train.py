@@ -7,7 +7,7 @@
       [--label-ratio 0.3] [--scale 0.15] [--feature-noise 3.0] \\
       [--signal-ratio 0.5] [--seed 0] [--impl reference] [--gossip-every 1] \\
       [--edge-mesh] [--sim-shard] [--json-out hist.json] \\
-      [--save-state s.npz] [--resume s.npz]
+      [--save-state s.npz] [--resume s.npz] [--profile DIR]
 
 Every method resolves through ``repro.core.registry`` — the same strategy
 compositions the benchmarks and examples use (see ``registry.names()`` /
@@ -45,6 +45,10 @@ function of (seed, round), so ``--resume`` reproduces it exactly, and
 ``--async-buffer M --delay-dist zero`` is bit-identical to synchronous
 FedAvg.
 
+``--profile DIR`` writes a ``jax.profiler`` trace of the set-up and the
+rounds under ``DIR``: the program's ``fgl.*`` host spans and the named
+scopes of its device ops (``docs/TRACING.md``).
+
 ``parse_args`` + ``build`` are the whole set-up (graph, partition, config,
 meshes, registry), so other drivers (``chip_smoke.py``) run exactly this
 path; ``main`` adds the fit, the report and the checkpoint files. The
@@ -53,10 +57,12 @@ classifier's hidden width is ``FGLConfig``'s paper default (64).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import json
 import math
 
 import jax
+from jax.profiler import TraceAnnotation as span
 
 from repro.checkpoint import io as ckpt_io
 from repro.core import registry
@@ -124,6 +130,10 @@ def parse_args(argv=None) -> argparse.Namespace:
                     help="write the final FGLState to this .npz")
     ap.add_argument("--resume", default="",
                     help="restore an FGLState .npz and continue at its round")
+    ap.add_argument("--profile", default="",
+                    help="write a jax.profiler trace of the set-up and the "
+                         "rounds (fgl.* spans, named scopes; docs/TRACING.md) "
+                         "under this directory")
     ap.add_argument("--edge-mesh", action="store_true",
                     help="shard the [N] edge-server axis across devices "
                          "(SpreadFGL only)")
@@ -176,11 +186,23 @@ def parse_args(argv=None) -> argparse.Namespace:
 def build(args: argparse.Namespace):
     """Graph -> client partition -> config -> meshes -> registered trainer.
 
-    Returns ``(trainer, batch)``; prints what it built.
+    Returns ``(trainer, batch)``; prints what it built. Under
+    ``jax.profiler`` the set-up is a ``fgl.build`` span with the children
+    ``fgl.build/graph``, ``fgl.build/partition`` and ``fgl.build/trainer``
+    (``docs/TRACING.md``).
     """
-    graph = make_sbm_graph(DATASETS[args.dataset], scale=args.scale,
-                           seed=args.seed + 1, feature_noise=args.feature_noise,
-                           signal_ratio=args.signal_ratio)
+    with span("fgl.build"):
+        with span("fgl.build/graph"):
+            graph = make_sbm_graph(DATASETS[args.dataset], scale=args.scale,
+                                   seed=args.seed + 1, feature_noise=args.feature_noise,
+                                   signal_ratio=args.signal_ratio)
+        with span("fgl.build/partition"):
+            batch = _partition(args, graph)
+        with span("fgl.build/trainer"):
+            return _trainer(args, batch), batch
+
+
+def _partition(args: argparse.Namespace, graph):
     part = make_partitioner(args.partitioner, alpha=args.alpha)
     batch, assign = partition_graph(graph, args.clients, aug_max=12,
                                     seed=args.seed, label_ratio=args.label_ratio,
@@ -190,6 +212,10 @@ def build(args: argparse.Namespace):
           f"{count_missing_links(graph, assign)} missing cross-client links")
     print(f"[fgl] partitioner={args.partitioner} "
           f"mean client label entropy={ent.mean():.3f} nats")
+    return batch
+
+
+def _trainer(args: argparse.Namespace, batch):
     if args.participation < 1.0:
         n_part = max(1, math.ceil(args.participation * args.clients))
         print(f"[fgl] partial participation: rho={args.participation} "
@@ -239,21 +265,26 @@ def build(args: argparse.Namespace):
         print(f"[fgl] async aggregation: buffer B={args.async_buffer} of "
               f"M={args.clients}, delays={args.delay_dist}, "
               f"dropout={args.dropout_rate}")
-    return registry.build(args.method, cfg, batch, **kw), batch
+    return registry.build(args.method, cfg, batch, **kw)
 
 
 def main() -> None:
     args = parse_args()
     compile_cache.enable()
-    tr, batch = build(args)
-    if args.resume:
-        state = ckpt_io.restore(args.resume,
-                                tr.init(jax.random.key(args.seed), batch))
-        print(f"[fgl] resumed {args.resume} at round {state.round}")
-        state, hist = tr.fit(state=state, rounds=args.rounds)
-    else:
-        state, hist = tr.fit(jax.random.key(args.seed), batch,
-                             rounds=args.rounds)
+    with (jax.profiler.trace(args.profile) if args.profile
+          else contextlib.nullcontext()):
+        tr, batch = build(args)
+        if args.resume:
+            state = ckpt_io.restore(args.resume,
+                                    tr.init(jax.random.key(args.seed), batch))
+            print(f"[fgl] resumed {args.resume} at round {state.round}")
+            state, hist = tr.fit(state=state, rounds=args.rounds)
+        else:
+            state, hist = tr.fit(jax.random.key(args.seed), batch,
+                                 rounds=args.rounds)
+    if args.profile:
+        print(f"[fgl] profile of the set-up and {args.rounds} rounds "
+              f"written under {args.profile}")
     for i, r in enumerate(hist["round"]):
         print(f"[fgl] round {r:3d} loss={hist['loss'][i]:8.4f} "
               f"acc={hist['acc'][i]:.3f} f1={hist['f1'][i]:.3f}")
