@@ -18,9 +18,13 @@ from repro.kernels import sage_aggregate as _sage
 from repro.kernels import sim_topk as _sim
 
 
+def _round_up(size: int, multiple: int) -> int:
+    return -(-size // multiple) * multiple
+
+
 def _pad_to(x: jnp.ndarray, axis: int, multiple: int, value=0.0) -> jnp.ndarray:
     size = x.shape[axis]
-    target = ((size + multiple - 1) // multiple) * multiple
+    target = _round_up(size, multiple)
     if target == size:
         return x
     pads = [(0, 0)] * x.ndim
@@ -86,13 +90,40 @@ def _sage_aggregate_bwd(block_m, block_n, block_k, interpret, res, g):
 _sage_aggregate.defvjp(_sage_aggregate_fwd, _sage_aggregate_bwd)
 
 
+# Caps on sage_aggregate's (block_m, block_n, block_k) by device_kind, from
+# a sweep on the chip (PERF.md). Kinds not listed, and interpret mode, take
+# the default, whose largest tiles fit the default scoped VMEM.
+_SAGE_CAPS = {"TPU v5 lite": (512, 1152, 512)}
+_SAGE_CAPS_DEFAULT = (512, 512, 512)
+
+
+def _divisor_tile(size: int, cap: int) -> int:
+    """The largest multiple of 128 that divides the 128-padded size and is at
+    most ``cap``: a larger tile never adds padding."""
+    units = _round_up(size, 128) // 128
+    return 128 * max(u for u in range(1, cap // 128 + 1) if units % u == 0)
+
+
+def sage_tiles(n: int, d: int, device_kind: Optional[str] = None):
+    """(block_m, block_n, block_k) of sage_aggregate for adj [n, n], h [n, d]
+    on a chip of ``device_kind``."""
+    cap_m, cap_n, cap_k = _SAGE_CAPS.get(device_kind, _SAGE_CAPS_DEFAULT)
+    return (_divisor_tile(n, cap_m), _divisor_tile(d, cap_n),
+            _divisor_tile(n, cap_k))
+
+
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "block_k",
                                              "interpret"))
-def sage_aggregate(adj: jnp.ndarray, h: jnp.ndarray, *, block_m: int = 128,
-                   block_n: int = 128, block_k: int = 128,
+def sage_aggregate(adj: jnp.ndarray, h: jnp.ndarray, *,
+                   block_m: Optional[int] = None, block_n: Optional[int] = None,
+                   block_k: Optional[int] = None,
                    interpret: bool = False) -> jnp.ndarray:
-    """Row-normalized neighbor aggregation; accepts arbitrary [n,n]/[n,d]."""
-    return _sage_aggregate(adj, h, block_m, block_n, block_k, interpret)
+    """Row-normalized neighbor aggregation; accepts arbitrary [n,n]/[n,d].
+    A block left as None comes from ``sage_tiles`` for this shape and chip."""
+    kind = None if interpret else jax.devices()[0].device_kind
+    bm, bn, bk = sage_tiles(*h.shape, kind)
+    return _sage_aggregate(adj, h, block_m or bm, block_n or bn, block_k or bk,
+                           interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n", "interpret"))

@@ -5,8 +5,12 @@ neighbor (contraction) dimension that accumulates both the aggregate and the
 row degree in VMEM scratch, dividing on the last contraction step. Saves one
 full read of A versus materializing the degree separately.
 
-Grid: (row_blocks, col_blocks, k_blocks), k innermost. Tiles default to
-128×128 (MXU-aligned); A tiles and H tiles stream HBM→VMEM.
+Grid: (row_blocks, col_blocks, k_blocks), k innermost; A tiles and H tiles
+stream HBM→VMEM. ``ops.sage_tiles`` picks the tiles from the shape and the
+chip: multiples of 128 that divide the 128-padded sizes, so a larger tile
+never adds padding, up to caps measured per ``device_kind``. The kernel asks
+the compiler for twice the VMEM its buffers take (``vmem_limit_bytes``),
+room for the temporaries of the f32 dot.
 """
 from __future__ import annotations
 
@@ -16,6 +20,24 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+LANE = 128
+DEFAULT_SCOPED_VMEM = 16 << 20  # Mosaic's scoped VMEM on v5e when none is asked
+
+
+def vmem_bytes(block_m: int, block_n: int, block_k: int) -> int:
+    """VMEM the kernel's buffers take, reckoned in f32: the double-buffered
+    A, H and output tiles, the accumulator, and the degree column (one lane
+    tile wide)."""
+    return 4 * (2 * (block_m * block_k + block_k * block_n + block_m * block_n)
+                + block_m * block_n + block_m * LANE)
+
+
+def vmem_limit_bytes(block_m: int, block_n: int, block_k: int) -> int:
+    """Scoped VMEM the kernel asks for: twice its buffers, at least the
+    default."""
+    return max(2 * vmem_bytes(block_m, block_n, block_k), DEFAULT_SCOPED_VMEM)
 
 
 def _sage_kernel(a_ref, h_ref, o_ref, acc_scratch, deg_scratch):
@@ -64,5 +86,7 @@ def sage_aggregate(adj: jnp.ndarray, h: jnp.ndarray, *, block_m: int = 128,
             pltpu.VMEM((block_m, block_n), jnp.float32),
             pltpu.VMEM((block_m, 1), jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes(block_m, block_n, block_k)),
         interpret=interpret,
     )(adj, h)

@@ -1,11 +1,14 @@
 """Per-kernel validation: interpret-mode Pallas vs pure-jnp oracles,
 swept over shapes and dtypes (assert_allclose)."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels import sage_aggregate as sage_kernel
 
 KEY = jax.random.key(42)
 
@@ -48,17 +51,96 @@ def test_flash_attention_first_row_attends_self_only():
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("n,d", [(64, 32), (100, 70), (256, 128), (300, 129), (37, 5)])
-def test_sage_aggregate_matches_oracle(n, d, dtype):
-    a = (jax.random.uniform(jax.random.fold_in(KEY, n), (n, n)) < 0.15
+@pytest.mark.parametrize("n,d,clients", [
+    (64, 32, None), (100, 70, None), (256, 128, None), (300, 129, None),
+    (37, 5, None),
+    (1100, 1700, None),   # tiles (384, 256, 384) over a (3, 7, 3) grid
+    (600, 300, 3),        # vmapped over clients, as FGLTrainer runs it
+], ids=["64-32", "100-70", "256-128", "300-129", "37-5", "1100-1700",
+        "600-300-x3"])
+def test_sage_aggregate_matches_oracle(n, d, clients, dtype):
+    lead = () if clients is None else (clients,)
+    a = (jax.random.uniform(jax.random.fold_in(KEY, n), lead + (n, n)) < 0.15
          ).astype(dtype)
-    h = _rand(jax.random.fold_in(KEY, n + d), (n, d), dtype)
-    out = ops.sage_aggregate(a, h, interpret=True)
-    expect = ref.sage_aggregate(a, h)
+    h = _rand(jax.random.fold_in(KEY, n + d), lead + (n, d), dtype)
+    agg = functools.partial(ops.sage_aggregate, interpret=True)
+    if clients is None:
+        out = agg(a, h)
+        expect = ref.sage_aggregate(a, h)
+    else:
+        out = jax.vmap(agg)(a, h)
+        expect = jax.vmap(ref.sage_aggregate)(a, h)
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(expect, np.float32),
                                atol=tol, rtol=tol)
+
+
+def _pallas_call(fn, *shapes):
+    """The pallas_call equation in the jaxpr of ``fn`` at ``shapes`` (f32)."""
+    def find(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                return eqn
+            for param in eqn.params.values():
+                inner = getattr(param, "jaxpr", None)
+                if inner is not None:
+                    found = find(getattr(inner, "jaxpr", inner))
+                    if found is not None:
+                        return found
+        return None
+    specs = [jax.ShapeDtypeStruct(s, jnp.float32) for s in shapes]
+    return find(jax.make_jaxpr(fn)(*specs).jaxpr)
+
+
+def _pad128(size):
+    return -(-size // 128) * 128
+
+
+V5E_VMEM_BUDGET = 64 << 20   # half of a v5e core's 128 MiB of VMEM
+
+
+@pytest.mark.parametrize("kind", ["TPU v5 lite", None], ids=["v5e", "default"])
+@pytest.mark.parametrize("n,d", [(914, 1433), (914, 64), (6123, 6805),
+                                 (6123, 64), (689, 1433), (300, 1433)],
+                         ids=["cora", "cora_hidden", "coauthor_cs",
+                              "coauthor_cs_hidden", "cora_n4", "small"])
+def test_sage_tiles_divide_the_padded_shape(n, d, kind):
+    """Tiles are multiples of 128 that divide the 128-padded sizes, so the
+    kernel runs on exactly the 128-padded operands, and its VMEM fits."""
+    bm, bn, bk = tiles = ops.sage_tiles(n, d, kind)
+    n_pad, d_pad = _pad128(n), _pad128(d)
+    for tile, size in zip(tiles, (n_pad, d_pad, n_pad)):
+        assert tile % 128 == 0 and size % tile == 0
+    if kind is None:   # interpret mode takes the default choice itself
+        fn = functools.partial(ops.sage_aggregate, interpret=True)
+    else:
+        fn = functools.partial(ops.sage_aggregate, block_m=bm, block_n=bn,
+                               block_k=bk, interpret=True)
+    eqn = _pallas_call(fn, (n, n), (n, d))
+    assert [v.aval.shape for v in eqn.invars] == [(n_pad, n_pad), (n_pad, d_pad)]
+    assert eqn.params["grid_mapping"].grid == (n_pad // bm, d_pad // bn,
+                                               n_pad // bk)
+    limit = sage_kernel.vmem_limit_bytes(*tiles)
+    if kind is None:
+        assert limit == sage_kernel.DEFAULT_SCOPED_VMEM
+    else:
+        assert limit <= V5E_VMEM_BUDGET
+    vmem = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    assert vmem == limit
+
+
+@pytest.mark.parametrize("blocks,grid", [
+    ({"block_m": 128, "block_n": 128, "block_k": 128}, (8, 12, 8)),
+    ({"block_n": 128}, (2, 12, 2)),
+    ({"block_m": 256, "block_k": 1024}, (4, 3, 1)),
+], ids=["all", "block_n", "block_m_k"])
+def test_sage_aggregate_explicit_blocks_override(blocks, grid):
+    """An explicit block is honoured; the others keep the chosen tiles."""
+    eqn = _pallas_call(functools.partial(ops.sage_aggregate, interpret=True,
+                                         **blocks), (914, 914), (914, 1433))
+    assert ops.sage_tiles(914, 1433) == (512, 512, 512)
+    assert eqn.params["grid_mapping"].grid == grid
 
 
 def test_sage_aggregate_isolated_nodes_zero():

@@ -14,8 +14,12 @@ Table-I graphs at full size, label-propagation partition, 12 aug slots):
   n_pad = 689, n = 1378; CoauthorCS with N=3, M=6 gives n_pad = 6123,
   n = 12246, c = 15. n = 300 is a graph small enough that ``ops`` shrinks
   the column block below 512 and off the 128-lane grid.
-- ``sage_aggregate``: one Cora client, adj [914, 914], at the input width
-  (1,433 features) and the hidden width (64), forward and gradient.
+- ``sage_aggregate``: forward and gradient at the input width and the hidden
+  width (64), with the tiles ``ops.sage_tiles`` picks for a v5e: one Cora
+  client, adj [914, 914] at 1,433 features; and CoauthorCS's six clients,
+  adj [6, 6123, 6123] at 6,805 features, vmapped as ``FGLTrainer`` runs
+  them. That proves the chip's compiler accepts the tiles within the VMEM
+  the kernel asks for.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -79,15 +83,27 @@ def test_sim_topk_compiles(one_chip, n, c):
     _assert_kernel(compiled)
 
 
-@pytest.mark.parametrize("d", [1433, 64], ids=["input_width", "hidden_width"])
-def test_sage_aggregate_forward_and_grad_compile(one_chip, d):
-    n = 914
+@pytest.mark.parametrize("n,d,clients", [
+    (914, 1433, None),   # one Cora client
+    (914, 64, None),
+    (6123, 6805, 6),     # CoauthorCS, six clients
+    (6123, 64, 6),
+], ids=["input_width", "hidden_width", "coauthor_cs_input_width",
+        "coauthor_cs_hidden_width"])
+def test_sage_aggregate_forward_and_grad_compile(one_chip, n, d, clients):
+    # A test process sees the CPU, so pass the v5e's choice explicitly.
+    bm, bn, bk = ops.sage_tiles(n, d, "TPU v5 lite")
+
+    def agg(adj, h):
+        return ops.sage_aggregate(adj, h, block_m=bm, block_n=bn, block_k=bk)
 
     def loss(adj, h, g):
-        return jnp.sum(ops.sage_aggregate(adj, h) * g)
+        out = agg(adj, h) if clients is None else jax.vmap(agg)(adj, h)
+        return jnp.sum(out * g)
 
+    lead = () if clients is None else (clients,)
     fn = jax.jit(jax.value_and_grad(loss, argnums=1))
-    compiled = fn.lower(_spec((n, n), jnp.float32, one_chip),
-                        _spec((n, d), jnp.float32, one_chip),
-                        _spec((n, d), jnp.float32, one_chip)).compile()
+    compiled = fn.lower(_spec(lead + (n, n), jnp.float32, one_chip),
+                        _spec(lead + (n, d), jnp.float32, one_chip),
+                        _spec(lead + (n, d), jnp.float32, one_chip)).compile()
     _assert_kernel(compiled)
