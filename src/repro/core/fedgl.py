@@ -46,12 +46,67 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import assessor as assessor_lib
-from repro.core import gnn, imputation, strategies
+from repro.core import gnn, imputation, patcher, strategies
 from repro.core import imputation as imputation_lib  # the ctor arg shadows it
 from repro.core.types import ClientBatch, FGLConfig
 from repro.optim.adam import Adam
 
 PyTree = Any
+
+
+class _ForwardingJit:
+    """``jax.jit(fn)`` whose program leaves out every output that hands back
+    one of its inputs unchanged; a call returns those inputs themselves.
+
+    A TPU runtime allocates a buffer for each output of a program when it
+    launches it, about 50 us each on a v5e host, and an imputation round
+    hands back 24 of its 73 state leaves untouched (the classifiers, their
+    optimizer state, most of the batch). Which outputs are forwarded is read
+    from the traced jaxpr, once per argument structure and types; the
+    program keeps ``fn``'s name, so it is the module ``jit_<name>``. The
+    arguments ``donate_argnums`` names are donated to the program (an output
+    takes over each buffer, with no allocation) and are never forwarded.
+    """
+
+    def __init__(self, fn, donate_argnums=()):
+        self._fn = fn
+        self._donate = tuple(donate_argnums)
+        self._plans: Dict[Any, Tuple[Any, list, Any]] = {}
+
+    def _plan(self, args):
+        leaves, in_tree = jax.tree.flatten(args)
+        sig = (in_tree, tuple(x.aval if isinstance(x, jax.Array) else jax.typeof(x)
+                              for x in leaves))
+        plan = self._plans.get(sig)
+        if plan is None:
+            traced = jax.jit(self._fn).trace(*args)
+            invars = traced.jaxpr.jaxpr.invars
+            assert len(invars) == len(leaves), (len(invars), len(leaves))
+            first = np.cumsum([0] + [len(jax.tree.leaves(a)) for a in args])
+            donated = {j for a in self._donate for j in range(first[a], first[a + 1])}
+            fwd = [next((j for j, v in enumerate(invars)
+                         if v is out and j not in donated), None)
+                   for out in traced.jaxpr.jaxpr.outvars]
+            made = [i for i, j in enumerate(fwd) if j is None]
+            fn = self._fn
+
+            @functools.wraps(fn)
+            def program(*a):
+                outs = jax.tree.leaves(fn(*a))
+                return [outs[i] for i in made]
+            plan = self._plans[sig] = (jax.jit(program, donate_argnums=self._donate), fwd,
+                                       jax.tree.structure(traced.out_info))
+        return leaves, plan
+
+    def __call__(self, *args):
+        leaves, (program, fwd, out_tree) = self._plan(args)
+        made = iter(program(*args))
+        return jax.tree.unflatten(
+            out_tree, [next(made) if j is None else leaves[j] for j in fwd])
+
+    def lower(self, *args):
+        """The lowering of the program a call with ``args`` runs."""
+        return self._plan(args)[1][0].lower(*args)
 
 
 @jax.tree_util.register_dataclass
@@ -161,8 +216,11 @@ class FGLTrainer:
         # Unscheduled aggregators have period 1.
         self._agg_period = max(1, int(getattr(self.aggregator, "period", 1)))
         self._agg_fn = jax.jit(self._aggregate, static_argnames=("round",))
-        self._impute_fn = jax.jit(self._impute)
+        self._impute_fn = _ForwardingJit(self._impute)
+        # step()'s own call: the generator state (argument 1) is donated
+        self._impute_step_fn = _ForwardingJit(self._impute, donate_argnums=(1,))
         self._eval_fn = jax.jit(self._evaluate)
+        self._no_links = jnp.zeros((), jnp.int32)  # `links` of a round without imputation
 
     # -- initialization ------------------------------------------------------
 
@@ -332,6 +390,13 @@ class FGLTrainer:
     def _train_generator(self, key, ae, ae_opt, asr, as_opt, h_real, flat_mask):
         """Alternating AE / assessor training (Algorithm 1 lines 16-23).
 
+        Each of the ``ae_outer_iters`` passes trains the autoencoder against
+        the assessor as it stands at the start of the pass, then the assessor
+        against that pass's autoencoder. Both counterparts travel in the
+        scans' carries, never in a closure: a ``lax.scan`` body is traced
+        once and its trace reused, so a name rebound between scans would
+        hand every pass the first pass's counterpart.
+
         The noise matrix S is sampled ONCE per imputation round (the only
         randomness here) and held fixed across AE/assessor iterations, so
         that row v of S is bound to node v: the masked reconstruction term of
@@ -349,42 +414,45 @@ class FGLTrainer:
         _, ks = jax.random.split(key)
         s_noise = imputation.sample_noise(ks, n, self.num_classes)
 
-        def ae_step(carry, _):
-            ae, ae_opt = carry
-            s = s_noise
+        def ae_loss(ae, asr):
             if self.use_assessor:
-                loss_fn = lambda p: assessor_lib.autoencoder_loss(
-                    p, asr_current[0], s, h_real, e, flat_mask)
-            else:
-                # w/o assessor: plain masked reconstruction of H (Fig. 7 ablation).
-                def loss_fn(p):
-                    _, h_fake = imputation.reconstruct(p, s)
-                    diff = (h_real - h_fake)
-                    return jnp.sum(jnp.sum(diff * diff, -1) * flat_mask) / jnp.maximum(
-                        jnp.sum(flat_mask), 1.0)
-            grads = jax.grad(loss_fn)(ae)
+                return assessor_lib.autoencoder_loss(ae, asr, s_noise, h_real, e,
+                                                     flat_mask)
+            # w/o assessor: plain masked reconstruction of H (Fig. 7 ablation).
+            _, h_fake = imputation.reconstruct(ae, s_noise)
+            diff = h_real - h_fake
+            return jnp.sum(jnp.sum(diff * diff, -1) * flat_mask) / jnp.maximum(
+                jnp.sum(flat_mask), 1.0)
+
+        def as_loss(asr, h_fake):
+            if self.use_ns:
+                return assessor_lib.assessor_loss(asr, h_real, h_fake, e, flat_mask)
+            return assessor_lib.assessor_loss_plain(asr, h_real, h_fake, flat_mask)
+
+        def ae_step(carry, _):
+            ae, ae_opt, asr = carry                 # asr: frozen this pass
+            grads = jax.grad(ae_loss)(ae, asr)
             ae, ae_opt = self.gen_opt.update(grads, ae_opt, ae)
-            return (ae, ae_opt), ()
+            return (ae, ae_opt, asr), ()
 
         def as_step(carry, _):
-            asr, as_opt = carry
-            _, h_fake = imputation.reconstruct(ae_current[0], s_noise)
-            if self.use_ns:
-                loss_fn = lambda p: assessor_lib.assessor_loss(p, h_real, h_fake, e, flat_mask)
-            else:
-                loss_fn = lambda p: assessor_lib.assessor_loss_plain(p, h_real, h_fake, flat_mask)
-            grads = jax.grad(loss_fn)(asr)
+            asr, as_opt, h_fake = carry             # h_fake: this pass's AE
+            grads = jax.grad(as_loss)(asr, h_fake)
             asr, as_opt = self.gen_opt.update(grads, as_opt, asr)
-            return (asr, as_opt), ()
+            return (asr, as_opt, h_fake), ()
 
-        for _ in range(cfg.ae_outer_iters):
-            asr_current = (asr, as_opt)
-            (ae, ae_opt), _ = jax.lax.scan(ae_step, (ae, ae_opt), None,
-                                           length=cfg.ae_iters)
-            ae_current = (ae, ae_opt)
+        def outer_pass(carry, _):
+            ae, ae_opt, asr, as_opt = carry
+            (ae, ae_opt, _), _ = jax.lax.scan(ae_step, (ae, ae_opt, asr), None,
+                                              length=cfg.ae_iters)
             if self.use_assessor:
-                (asr, as_opt), _ = jax.lax.scan(as_step, (asr, as_opt), None,
-                                                length=cfg.assessor_iters)
+                _, h_fake = imputation.reconstruct(ae, s_noise)
+                (asr, as_opt, _), _ = jax.lax.scan(
+                    as_step, (asr, as_opt, h_fake), None, length=cfg.assessor_iters)
+            return (ae, ae_opt, asr, as_opt), ()
+
+        (ae, ae_opt, asr, as_opt), _ = jax.lax.scan(
+            outer_pass, (ae, ae_opt, asr, as_opt), None, length=cfg.ae_outer_iters)
         return ae, ae_opt, asr, as_opt, s_noise
 
     def _server_round_gen(self, key_j, ae, aeo, asr, aso, emb_j, mask_j):
@@ -416,8 +484,18 @@ class FGLTrainer:
                 kernel_impl=self.kernel_impl, target_mask=tmask)
         return ae, aeo, asr, aso, scores, idx, x_bar
 
-    def _impute(self, state: FGLState) -> FGLState:
-        return self.imputation.impute(self, state)
+    def _impute(self, state: FGLState, gen=None) -> Tuple[FGLState, jnp.ndarray]:
+        """The strategy's imputation round, and the number of imputed links
+        the patcher wrote into the client graphs (``patcher.link_count``).
+
+        ``gen``, where given, is the generator state ``(ae_params, ae_opt,
+        as_params, as_opt)`` passed apart from ``state`` (whose own are then
+        None), so that a call can donate it."""
+        if gen is not None:
+            state = dataclasses.replace(state, ae_params=gen[0], ae_opt=gen[1],
+                                        as_params=gen[2], as_opt=gen[3])
+        state = self.imputation.impute(self, state)
+        return state, patcher.link_count(state.batch)
 
     def _imputation_round_reference(self, state: FGLState) -> FGLState:
         """Sequential oracle of the vmapped generator round (tests/benchmarks).
@@ -471,7 +549,12 @@ class FGLTrainer:
         Local training, the strategy's imputation round when the absolute
         round index hits the every-K schedule, aggregation, then evaluation.
         Returns a new state at ``round + 1`` and metrics as device arrays
-        (``{"round", "loss", "acc", "f1"}``) — callers decide when to sync.
+        (``{"round", "loss", "acc", "f1", "links"}``; ``links`` counts the
+        imputed links written this round, 0 on a round without imputation)
+        — callers decide when to sync. The caller's state object is never
+        mutated, but on an imputation round its generator state (``ae_params``,
+        ``ae_opt``, ``as_params``, ``as_opt``) is donated to the new state's:
+        those arrays of the old state are deleted.
 
         Under ``jax.profiler`` the round is a ``fgl.round`` step span with
         one child span per dispatch (``fgl.local``, ``fgl.impute``,
@@ -484,12 +567,16 @@ class FGLTrainer:
         span = functools.partial(jax.profiler.TraceAnnotation, round=t)
         with jax.profiler.StepTraceAnnotation("fgl.round", step_num=t, round=t):
             state = dataclasses.replace(state)   # never mutate the caller's state
+            links = self._no_links
             with span("fgl.local"):
                 state.params, state.opt_state = self._local_fn(
                     state.params, state.opt_state, state.batch)
             if self.imputation.active and (t % self.cfg.imputation_interval == 0):
                 with span("fgl.impute"):
-                    state = self._impute_fn(state)
+                    gen = (state.ae_params, state.ae_opt, state.as_params, state.as_opt)
+                    state, links = self._impute_step_fn(
+                        dataclasses.replace(state, ae_params=None, ae_opt=None,
+                                            as_params=None, as_opt=None), gen)
             # The gossip phase, the participation mask, and the async flush
             # schedule are pure functions of the absolute round, so a state
             # restored mid-interval (or mid-buffer) resumes every schedule
@@ -501,7 +588,8 @@ class FGLTrainer:
             with span("fgl.evaluate"):
                 loss, acc, f1 = self._eval_fn(state.params, state.batch)
             state.round = t + 1
-        return state, {"round": t, "loss": loss, "acc": acc, "f1": f1}
+        return state, {"round": t, "loss": loss, "acc": acc, "f1": f1,
+                       "links": links}
 
     def fit(self, key: Optional[jax.Array] = None,
             batch: Optional[ClientBatch] = None, *,
@@ -541,5 +629,6 @@ class FGLTrainer:
             "loss": [float(m["loss"]) for m in metrics],
             "acc": [float(m["acc"]) for m in metrics],
             "f1": [float(m["f1"]) for m in metrics],
+            "links": [int(m["links"]) for m in metrics],
         }
         return state, history

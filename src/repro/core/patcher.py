@@ -100,6 +100,12 @@ def fix_graphs(batch: ClientBatch, link_scores: jnp.ndarray, link_idx: jnp.ndarr
     return batch.replace(x=x, adj=adj, node_mask=node_mask)
 
 
+def link_count(batch: ClientBatch) -> jnp.ndarray:
+    """Imputed links wired into the clients' graphs: one per filled
+    augmentation slot (each slot holds one imputed neighbor and its link)."""
+    return jnp.sum(batch.node_mask[:, batch.n_local_max:] > 0, dtype=jnp.int32)
+
+
 def clear_augmentation(batch: ClientBatch) -> ClientBatch:
     """Drop all imputed nodes/links (used by baselines and ablations)."""
     n_local = batch.n_local_max

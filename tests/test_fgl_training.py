@@ -97,6 +97,35 @@ class TestSpreadFGL:
         l_without = float(tr0._client_loss(state.params, state.batch))
         assert l_with > l_without  # Tr(W Wᵀ) > 0
 
+    def test_imputation_program_hands_back_unchanged_leaves_itself(self, setup):
+        """The imputation program outputs only the leaves it changes; the
+        rest come back as the very input arrays, and the whole result is the
+        plain jit's, bit for bit."""
+        _, batch, cfg = setup
+        tr = make_spreadfgl(cfg, batch, num_servers=3)
+        state, _ = tr.step(tr.init(jax.random.key(0), batch))
+        got, links = tr._impute_fn(state)
+        want, want_links = jax.jit(tr._impute)(state)
+        assert int(links) == int(want_links)
+        data = lambda x: jax.random.key_data(x) if jax.dtypes.issubdtype(
+            jnp.result_type(x), jax.dtypes.prng_key) else x
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(data(a)), np.asarray(data(b)))
+        for name in ("params", "opt_state"):
+            for a, b in zip(jax.tree.leaves(getattr(got, name)),
+                            jax.tree.leaves(getattr(state, name))):
+                assert a is b
+        for name in ("y", "train_mask", "test_mask"):
+            assert getattr(got.batch, name) is getattr(state.batch, name)
+        assert got.batch.x is not state.batch.x and got.ae_params is not state.ae_params
+        text = tr._impute_fn.lower(state).as_text()
+        assert text.startswith("module @jit__impute")
+        # forwarded: the classifiers, their optimizer state, four batch
+        # fields (y, train_mask, test_mask, global_id) and the round
+        forwarded = len(jax.tree.leaves((state.params, state.opt_state))) + 4 + 1
+        n_out = len(jax.tree.leaves((want, want_links)))
+        assert text.count("jax.result_info") == n_out - forwarded
+
 
 class TestBaselines:
     def test_local_never_aggregates(self, setup):
@@ -114,9 +143,10 @@ class TestBaselines:
         _, batch, cfg = setup
         tr = FedSagePlus(cfg, batch)
         state = tr.init(jax.random.key(0), batch)
-        state2 = tr._impute_fn(state)
+        state2, links = tr._impute_fn(state)
         n_local = state2.batch.n_local_max
         assert float(jnp.sum(state2.batch.node_mask[:, n_local:])) > 0
+        assert int(links) == int(jnp.sum(state2.batch.node_mask[:, n_local:] > 0))
 
     @pytest.mark.xfail(
         strict=False,

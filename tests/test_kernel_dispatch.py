@@ -28,7 +28,7 @@ def _round_outputs(tr, state):
     """(scores, idx, x_bar) per server plus the fixed state, via the real
     strategy path (SpreadImputation.server_outputs + impute)."""
     (_, _, _, _, scores, idx, x_bar), _ = tr.imputation.server_outputs(tr, state)
-    return scores, idx, x_bar, tr._impute_fn(state)
+    return scores, idx, x_bar, tr._impute_fn(state)[0]
 
 
 class TestImputationRoundParity:
@@ -64,7 +64,7 @@ class TestImputationRoundParity:
         tr_pls = make_spreadfgl(
             dataclasses.replace(cfg, kernel_impl="pallas_interpret"),
             batch, num_servers=2)
-        state = tr_ref._impute_fn(tr_ref.init(jax.random.key(0), batch))
+        state, _ = tr_ref._impute_fn(tr_ref.init(jax.random.key(0), batch))
         _, i_ref, _, out_ref = _round_outputs(tr_ref, state)
         _, i_pls, _, out_pls = _round_outputs(tr_pls, state)
         np.testing.assert_array_equal(np.asarray(i_pls), np.asarray(i_ref))
@@ -94,9 +94,10 @@ class TestAugSlotTargets:
             chosen = chosen[chosen >= 0]        # server-local flat slots
             assert (chosen % n_pad < n_local).all(), \
                 f"round {rnd}: aug slot chosen as link target"
-            state = tr._impute_fn(state)
+            state, links = tr._impute_fn(state)
             # round 1 precondition: the patcher did fill aug slots
             assert float(jnp.sum(state.batch.node_mask[:, n_local:])) > 0
+            assert int(links) == int(jnp.sum(state.batch.node_mask[:, n_local:] > 0))
 
     def test_aug_rows_do_not_source_links(self, small):
         """Aug-slot rows are invalid sources: their idx rows stay -1 after
@@ -104,7 +105,7 @@ class TestAugSlotTargets:
         fix_graphs' source filter keep them out)."""
         batch, cfg = small
         tr = make_spreadfgl(cfg, batch, num_servers=2)
-        state = tr._impute_fn(tr.init(jax.random.key(0), batch))
+        state, _ = tr._impute_fn(tr.init(jax.random.key(0), batch))
         emb = tr._embeddings(state.params, state.batch)
         _, flat_mask, _, tmask = imputation.search_inputs(
             emb[:tr.m_per], state.batch.node_mask[:tr.m_per], tr.n_local)

@@ -37,7 +37,7 @@ class TestStackedEquivalence:
     def test_vmapped_matches_sequential_loop(self, setup2):
         """vmap over the [N] axis == the seed's per-server Python loop."""
         tr, state = setup2
-        out_v = tr._impute_fn(state)
+        out_v, _ = tr._impute_fn(state)
         out_s = jax.jit(tr._imputation_round_reference)(state)
         # batch (graph fixing), generator params + opt states all agree.
         for field in ("batch", "ae_params", "ae_opt", "as_params", "as_opt"):
@@ -116,7 +116,7 @@ class TestCheckpointStackedState:
         path = os.path.join(tempfile.mkdtemp(), "fgl_state.npz")
         io.save(path, state)
         restored = io.restore(path, state)
-        out = tr._impute_fn(restored)
+        out, _ = tr._impute_fn(restored)
         for leaf in jax.tree.leaves(out.batch):
             assert np.isfinite(np.asarray(leaf, np.float32)).all()
 

@@ -20,6 +20,12 @@ Table-I graphs at full size, label-propagation partition, 12 aug slots):
   adj [6, 6123, 6123] at 6,805 features, vmapped as ``FGLTrainer`` runs
   them. That proves the chip's compiler accepts the tiles within the VMEM
   the kernel asks for.
+- the imputation program (``FGLTrainer._impute``, module ``jit__impute``)
+  as ``fgl_train --impl pallas`` builds it for the benchmark's configuration
+  ``cora-sage-n3m6`` (``benchmarks/chip/configs/``) with one local step and
+  an imputation round a round: Cora, N=3, M=6, n_pad 914, 12 aug slots,
+  k 4. It holds both kernels: ``sage_aggregate`` for the embeddings,
+  ``sim_topk`` for the links.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -107,3 +113,23 @@ def test_sage_aggregate_forward_and_grad_compile(one_chip, n, d, clients):
                         _spec(lead + (n, d), jnp.float32, one_chip),
                         _spec(lead + (n, d), jnp.float32, one_chip)).compile()
     _assert_kernel(compiled)
+
+
+def test_imputation_program_compiles(one_chip, monkeypatch):
+    from repro.launch import fgl_train
+    # The process sees the CPU: give it the v5e's sage_aggregate tiles.
+    monkeypatch.setitem(ops._SAGE_CAPS, jax.devices()[0].device_kind,
+                        ops._SAGE_CAPS["TPU v5 lite"])
+    args = fgl_train.parse_args([
+        "--dataset", "cora", "--servers", "3", "--clients", "6", "--scale", "1.0",
+        "--local-rounds", "1", "--imputation-interval", "1", "--top-k", "4",
+        "--impl", "pallas"])
+    tr, batch = fgl_train.build(args)
+    assert (tr.n_servers, tr.m, batch.n_pad, batch.aug_max) == (3, 6, 914, 12)
+    shapes = jax.eval_shape(tr.init, jax.random.key(0), batch)
+    state = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), shapes)
+    text = tr._impute_fn.lower(state).compile().as_text()
+    assert text.startswith("HloModule jit__impute")
+    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    assert any("sim_topk" in line for line in kernels)
+    assert any("sage_aggregate" in line for line in kernels)
