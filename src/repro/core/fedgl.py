@@ -217,15 +217,18 @@ class FGLTrainer:
         self._agg_period = max(1, int(getattr(self.aggregator, "period", 1)))
         self._agg_fn = jax.jit(self._aggregate, static_argnames=("round",))
         self._impute_fn = _ForwardingJit(self._impute)
-        # step()'s own call: the generator state (argument 1) is donated
-        self._impute_step_fn = _ForwardingJit(self._impute, donate_argnums=(1,))
+        # step()'s own call: the generator state and the batch's cache
+        # (arguments 1 and 2) are donated
+        self._impute_step_fn = _ForwardingJit(self._impute, donate_argnums=(1, 2))
         self._eval_fn = jax.jit(self._evaluate)
+        self._propagate_fn = jax.jit(self._propagate)
         self._no_links = jnp.zeros((), jnp.int32)  # `links` of a round without imputation
 
     # -- initialization ------------------------------------------------------
 
     def init(self, key: jax.Array, batch: ClientBatch) -> FGLState:
-        """Algorithm 1 lines 1-5: a fresh ``FGLState`` at round 0."""
+        """Algorithm 1 lines 1-5: a fresh ``FGLState`` at round 0, its batch
+        carrying the classifier's cache (``ClientBatch.prop``)."""
         cfg = self.cfg
         dims = [self.feature_dim] + [cfg.hidden_dim] * (cfg.num_layers - 1) + [self.num_classes]
         k_cls, k_ae, k_as, k_run = jax.random.split(key, 4)
@@ -241,6 +244,7 @@ class FGLTrainer:
         ae_params, ae_opt, as_params, as_opt = self._shard_edge(
             (ae_params, ae_opt, as_params, as_opt))
         batch = jax.tree.map(jnp.asarray, batch)
+        batch = batch.replace(prop=self._propagate_fn(batch))
         return FGLState(params=params, opt_state=self.opt.init(params),
                         ae_params=ae_params, ae_opt=ae_opt,
                         as_params=as_params, as_opt=as_opt,
@@ -278,18 +282,34 @@ class FGLTrainer:
                              PartitionSpec(self.edge_mesh.axis_names[0]))
         return jax.tree.map(lambda x: jax.device_put(x, spec), tree)
 
+    # -- the classifier's forward ---------------------------------------------
+
+    def _propagate(self, batch: ClientBatch) -> PyTree:
+        """``gnn.propagate`` of every client: the classifier's work on the
+        batch alone, which the batch caches as ``prop`` (scope
+        ``propagate``). ``init`` and ``_impute`` fill the cache with it."""
+        def one(x, adj, node_mask):
+            return gnn.propagate(self.cfg.gnn_kind, x, adj, node_mask,
+                                 impl=self.kernel_impl)
+        with jax.named_scope("propagate"):
+            return self.vmap(one)(batch.x, batch.adj, batch.node_mask)
+
+    def _forward(self, params, x, adj, node_mask, prop):
+        """One client's logits, from the batch's cache where it has one."""
+        return gnn.apply_classifier(params, self.cfg.gnn_kind, x, adj, node_mask,
+                                    impl=self.kernel_impl, prop=prop)
+
     # -- local training (Algorithm 1 lines 8-9) ------------------------------
 
     def _client_loss(self, params_m: PyTree, batch: ClientBatch) -> jnp.ndarray:
-        def one(params, x, adj, y, node_mask, train_mask):
-            logits = gnn.apply_classifier(params, self.cfg.gnn_kind, x, adj, node_mask,
-                                          impl=self.kernel_impl)
+        def one(params, x, adj, y, node_mask, train_mask, prop):
+            logits = self._forward(params, x, adj, node_mask, prop)
             loss = _cross_entropy(logits, y, train_mask)
             if self.is_spread and self.cfg.trace_reg > 0:
                 loss = loss + self.cfg.trace_reg * _trace_reg(params)
             return loss
         losses = self.vmap(one)(params_m, batch.x, batch.adj, batch.y,
-                                batch.node_mask, batch.train_mask)
+                                batch.node_mask, batch.train_mask, batch.prop)
         return jnp.sum(losses)  # sum => per-client grads stay independent
 
     def _local_rounds(self, params, opt_state, batch: ClientBatch):
@@ -381,11 +401,9 @@ class FGLTrainer:
     # -- imputation helpers shared by the strategies --------------------------
 
     def _embeddings(self, params, batch: ClientBatch) -> jnp.ndarray:
-        def one(p, x, adj, mask):
-            logits = gnn.apply_classifier(p, self.cfg.gnn_kind, x, adj, mask,
-                                          impl=self.kernel_impl)
-            return jax.nn.softmax(logits, axis=-1)
-        return self.vmap(one)(params, batch.x, batch.adj, batch.node_mask)
+        def one(p, x, adj, mask, prop):
+            return jax.nn.softmax(self._forward(p, x, adj, mask, prop), axis=-1)
+        return self.vmap(one)(params, batch.x, batch.adj, batch.node_mask, batch.prop)
 
     def _train_generator(self, key, ae, ae_opt, asr, as_opt, h_real, flat_mask):
         """Alternating AE / assessor training (Algorithm 1 lines 16-23).
@@ -484,18 +502,24 @@ class FGLTrainer:
                 kernel_impl=self.kernel_impl, target_mask=tmask)
         return ae, aeo, asr, aso, scores, idx, x_bar
 
-    def _impute(self, state: FGLState, gen=None) -> Tuple[FGLState, jnp.ndarray]:
+    def _impute(self, state: FGLState, gen=None, prop=None
+                ) -> Tuple[FGLState, jnp.ndarray]:
         """The strategy's imputation round, and the number of imputed links
         the patcher wrote into the client graphs (``patcher.link_count``).
+        The patched batch leaves with its cache ``prop`` filled anew.
 
         ``gen``, where given, is the generator state ``(ae_params, ae_opt,
         as_params, as_opt)`` passed apart from ``state`` (whose own are then
-        None), so that a call can donate it."""
+        None), and ``prop`` the batch's cache passed apart from it, so that a
+        call can donate them."""
         if gen is not None:
             state = dataclasses.replace(state, ae_params=gen[0], ae_opt=gen[1],
                                         as_params=gen[2], as_opt=gen[3])
+        if prop is not None:
+            state = dataclasses.replace(state, batch=state.batch.replace(prop=prop))
         state = self.imputation.impute(self, state)
-        return state, patcher.link_count(state.batch)
+        batch = state.batch.replace(prop=self._propagate(state.batch))
+        return dataclasses.replace(state, batch=batch), patcher.link_count(batch)
 
     def _imputation_round_reference(self, state: FGLState) -> FGLState:
         """Sequential oracle of the vmapped generator round (tests/benchmarks).
@@ -509,9 +533,8 @@ class FGLTrainer:
 
     def _evaluate(self, params, batch: ClientBatch):
         """One compiled call per round: (mean client loss, accuracy, macro-F1)."""
-        def one(p, x, adj, y, node_mask, test_mask):
-            logits = gnn.apply_classifier(p, self.cfg.gnn_kind, x, adj, node_mask,
-                                          impl=self.kernel_impl)
+        def one(p, x, adj, y, node_mask, test_mask, prop):
+            logits = self._forward(p, x, adj, node_mask, prop)
             pred = jnp.argmax(logits, axis=-1)
             mask = test_mask * (y >= 0)
             correct = jnp.sum((pred == y) * mask)
@@ -525,7 +548,8 @@ class FGLTrainer:
             return correct, jnp.sum(mask), tp, fp, fn
         with jax.named_scope("evaluate"):
             correct, total, tp, fp, fn = self.vmap(one)(
-                params, batch.x, batch.adj, batch.y, batch.node_mask, batch.test_mask)
+                params, batch.x, batch.adj, batch.y, batch.node_mask, batch.test_mask,
+                batch.prop)
             acc = jnp.sum(correct) / jnp.maximum(jnp.sum(total), 1.0)
             tp, fp, fn = jnp.sum(tp, 0), jnp.sum(fp, 0), jnp.sum(fn, 0)
             precision = tp / jnp.maximum(tp + fp, 1e-9)
@@ -553,8 +577,9 @@ class FGLTrainer:
         imputed links written this round, 0 on a round without imputation)
         — callers decide when to sync. The caller's state object is never
         mutated, but on an imputation round its generator state (``ae_params``,
-        ``ae_opt``, ``as_params``, ``as_opt``) is donated to the new state's:
-        those arrays of the old state are deleted.
+        ``ae_opt``, ``as_params``, ``as_opt``) and its batch's cache
+        (``batch.prop``) are donated to the new state's: those arrays of the
+        old state are deleted.
 
         Under ``jax.profiler`` the round is a ``fgl.round`` step span with
         one child span per dispatch (``fgl.local``, ``fgl.impute``,
@@ -576,7 +601,9 @@ class FGLTrainer:
                     gen = (state.ae_params, state.ae_opt, state.as_params, state.as_opt)
                     state, links = self._impute_step_fn(
                         dataclasses.replace(state, ae_params=None, ae_opt=None,
-                                            as_params=None, as_opt=None), gen)
+                                            as_params=None, as_opt=None,
+                                            batch=state.batch.replace(prop=None)),
+                        gen, state.batch.prop)
             # The gossip phase, the participation mask, and the async flush
             # schedule are pure functions of the absolute round, so a state
             # restored mid-interval (or mid-buffer) resumes every schedule

@@ -61,13 +61,25 @@ def init_sage(key, dims: Sequence[int]) -> PyTree:
     return {"layers": params}
 
 
-def apply_sage(params: PyTree, x, adj, node_mask, *, impl: str = "reference"):
-    """Returns per-node logits [n, c]. Masked: padded rows output zeros."""
+def propagate_sage(x, adj, node_mask, *, impl: str = "reference") -> PyTree:
+    """The input-only work of ``apply_sage``: the normalized adjacency and
+    layer 1's aggregate of the masked features, which no weight enters."""
     a_norm = normalize_adjacency(adj, node_mask)
+    return {"a_norm": a_norm, "agg1": aggregate(a_norm, x * node_mask[..., None], impl)}
+
+
+def apply_sage(params: PyTree, x, adj, node_mask, *, impl: str = "reference",
+               prop: PyTree = None):
+    """Returns per-node logits [n, c]. Masked: padded rows output zeros.
+    ``prop`` is ``propagate_sage`` of the same inputs, computed here if None."""
+    if prop is None:
+        prop = propagate_sage(x, adj, node_mask, impl=impl)
     h = x * node_mask[..., None]
+    agg = prop["agg1"]
     n_layers = len(params["layers"])
     for li, layer in enumerate(params["layers"]):
-        agg = aggregate(a_norm, h, impl)
+        if li:
+            agg = aggregate(prop["a_norm"], h, impl)
         # [h || agg] W  ==  h W_self + agg W_nbr
         h = h @ layer["w_self"] + agg @ layer["w_nbr"] + layer["b"]
         if li < n_layers - 1:
@@ -88,14 +100,22 @@ def init_gcn(key, dims: Sequence[int]) -> PyTree:
     return {"layers": params}
 
 
-def apply_gcn(params: PyTree, x, adj, node_mask, *, impl: str = "reference"):
-    # Self loops then symmetric-ish (row) normalization.
+def propagate_gcn(x, adj, node_mask, *, impl: str = "reference") -> PyTree:
+    """``propagate_sage`` on the graph with self loops: row normalization of
+    ``adj + I``."""
     eye = jnp.eye(adj.shape[-1], dtype=adj.dtype)
-    a_norm = normalize_adjacency(adj + eye, node_mask)
+    return propagate_sage(x, adj + eye, node_mask, impl=impl)
+
+
+def apply_gcn(params: PyTree, x, adj, node_mask, *, impl: str = "reference",
+              prop: PyTree = None):
+    if prop is None:
+        prop = propagate_gcn(x, adj, node_mask, impl=impl)
     h = x * node_mask[..., None]
     n_layers = len(params["layers"])
     for li, layer in enumerate(params["layers"]):
-        h = aggregate(a_norm, h, impl) @ layer["w"] + layer["b"]
+        agg = aggregate(prop["a_norm"], h, impl) if li else prop["agg1"]
+        h = agg @ layer["w"] + layer["b"]
         if li < n_layers - 1:
             h = jax.nn.relu(h)
         h = h * node_mask[..., None]
@@ -119,8 +139,14 @@ def init_gat(key, dims: Sequence[int]) -> PyTree:
     return {"layers": params}
 
 
-def apply_gat(params: PyTree, x, adj, node_mask, *, impl: str = "reference"):
-    del impl
+def propagate_gat(x, adj, node_mask, *, impl: str = "reference") -> None:
+    """Nothing: GAT's first layer already reads its weights."""
+    del x, adj, node_mask, impl
+
+
+def apply_gat(params: PyTree, x, adj, node_mask, *, impl: str = "reference",
+              prop: PyTree = None):
+    del impl, prop
     mask2d = node_mask[..., :, None] * node_mask[..., None, :]
     eye = jnp.eye(adj.shape[-1], dtype=adj.dtype)
     a = (adj + eye) * mask2d
@@ -141,9 +167,9 @@ def apply_gat(params: PyTree, x, adj, node_mask, *, impl: str = "reference"):
 
 
 KINDS = {
-    "sage": (init_sage, apply_sage),
-    "gcn": (init_gcn, apply_gcn),
-    "gat": (init_gat, apply_gat),
+    "sage": (init_sage, apply_sage, propagate_sage),
+    "gcn": (init_gcn, apply_gcn, propagate_gcn),
+    "gat": (init_gat, apply_gat, propagate_gat),
 }
 
 
@@ -151,6 +177,15 @@ def init_classifier(key, kind: str, dims: Sequence[int]) -> PyTree:
     return KINDS[kind][0](key, dims)
 
 
+def propagate(kind: str, x, adj, node_mask, *, impl: str = "reference") -> PyTree:
+    """What classifier ``kind`` computes from one client's graph alone, before
+    any weight enters: ``apply_classifier``'s ``prop``."""
+    return KINDS[kind][2](x, adj, node_mask, impl=impl)
+
+
 def apply_classifier(params: PyTree, kind: str, x, adj, node_mask, *,
-                     impl: str = "reference"):
-    return KINDS[kind][1](params, x, adj, node_mask, impl=impl)
+                     impl: str = "reference", prop: PyTree = None):
+    """Logits of classifier ``kind``. ``prop``, where given, is ``propagate``
+    of the same ``x``, ``adj`` and ``node_mask``; where None the forward
+    computes it, to the same values."""
+    return KINDS[kind][1](params, x, adj, node_mask, impl=impl, prop=prop)

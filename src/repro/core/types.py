@@ -68,6 +68,13 @@ class ClientBatch:
     ``n_pad = n_local_max + aug_max``: the first ``n_local_max`` slots hold real
     local nodes, the trailing ``aug_max`` slots are reserved for imputed
     neighbors written by the graphic patcher (Sec. III-D).
+
+    ``prop`` caches what the client classifier computes from ``x``, ``adj``
+    and ``node_mask`` alone (``gnn.propagate``, stacked on ``[M]``), so that
+    the rounds' forwards do not recompute it. ``replace`` drops it whenever
+    one of those three is replaced; ``FGLTrainer.init`` and
+    ``FGLTrainer._impute`` fill it. A forward given a batch with no cache
+    computes it itself.
     """
 
     x: Array           # [M, n_pad, d] features (aug slots overwritten by patcher)
@@ -79,6 +86,7 @@ class ClientBatch:
     global_id: Array   # [M, n_pad] int32 index into the global graph (-1 pad)
     num_classes: int = dataclasses.field(metadata=dict(static=True))
     aug_max: int = dataclasses.field(metadata=dict(static=True))
+    prop: Any = None   # gnn.propagate of every client, or None
 
     @property
     def num_clients(self) -> int:
@@ -93,7 +101,14 @@ class ClientBatch:
         return self.n_pad - self.aug_max
 
     def replace(self, **kw) -> "ClientBatch":
+        """A copy with fields replaced; a new ``x``, ``adj`` or ``node_mask``
+        drops the cache ``prop`` unless ``prop`` is given too."""
+        if "prop" not in kw and not _CACHE_INPUTS.isdisjoint(kw):
+            kw["prop"] = None
         return dataclasses.replace(self, **kw)
+
+
+_CACHE_INPUTS = frozenset({"x", "adj", "node_mask"})
 
 
 @dataclasses.dataclass

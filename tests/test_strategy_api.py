@@ -109,8 +109,9 @@ class TestHistoryRegression:
 
     def test_an_imputation_round_takes_over_the_generator_state(self, small):
         """On an imputation round step() donates the old state's generator
-        state to the new one, and computes what the undonated program does;
-        the classifiers and the batch stay the caller's."""
+        state and its batch's cache to the new one, and computes what the
+        undonated program does; the classifiers and the batch's own fields
+        stay the caller's."""
         batch, cfg = small
         tr = make_spreadfgl(cfg, batch, num_servers=2)
         state = tr.init(jax.random.key(0), batch)
@@ -118,10 +119,11 @@ class TestHistoryRegression:
             state, params=tr._local_fn(state.params, state.opt_state, state.batch)[0]))
         new, m = tr.step(state)
         assert int(m["links"]) > 0
-        gen = (state.ae_params, state.ae_opt, state.as_params, state.as_opt)
-        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(gen))
+        donated = (state.ae_params, state.ae_opt, state.as_params, state.as_opt,
+                   state.batch.prop)
+        assert all(leaf.is_deleted() for leaf in jax.tree.leaves(donated))
         assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(
-            (state.params, state.opt_state, state.batch, new))
+            (state.params, state.opt_state, state.batch.replace(prop=None), new))
             if isinstance(leaf, jax.Array))
         for a, b in zip(jax.tree.leaves((new.ae_params, new.ae_opt, new.as_params,
                                          new.as_opt, new.batch)),

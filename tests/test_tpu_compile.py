@@ -26,6 +26,11 @@ Table-I graphs at full size, label-propagation partition, 12 aug slots):
   an imputation round a round: Cora, N=3, M=6, n_pad 914, 12 aug slots,
   k 4. It holds both kernels: ``sage_aggregate`` for the embeddings,
   ``sim_topk`` for the links.
+- the round's programs of that configuration, counted for ``sage_aggregate``
+  calls: layer 1's aggregate is the batch's cache (``ClientBatch.prop``), so
+  the local and evaluation programs run the kernel once (layer 2), and the
+  imputation program twice (layer 2 of its embeddings, and layer 1 of the
+  patched graphs as it fills the cache anew).
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
@@ -115,7 +120,10 @@ def test_sage_aggregate_forward_and_grad_compile(one_chip, n, d, clients):
     _assert_kernel(compiled)
 
 
-def test_imputation_program_compiles(one_chip, monkeypatch):
+@pytest.fixture()
+def cora_n3m6(one_chip, monkeypatch):
+    """The trainer ``fgl_train --impl pallas`` builds for ``cora-sage-n3m6``
+    with ``impute-k1``, and its initial state as shapes on the v5e."""
     from repro.launch import fgl_train
     # The process sees the CPU: give it the v5e's sage_aggregate tiles.
     monkeypatch.setitem(ops._SAGE_CAPS, jax.devices()[0].device_kind,
@@ -127,9 +135,35 @@ def test_imputation_program_compiles(one_chip, monkeypatch):
     tr, batch = fgl_train.build(args)
     assert (tr.n_servers, tr.m, batch.n_pad, batch.aug_max) == (3, 6, 914, 12)
     shapes = jax.eval_shape(tr.init, jax.random.key(0), batch)
-    state = jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), shapes)
+    return tr, jax.tree.map(lambda s: _spec(s.shape, s.dtype, one_chip), shapes)
+
+
+def _kernel_calls(text):
+    return [line for line in text.splitlines() if "tpu_custom_call" in line]
+
+
+def test_imputation_program_compiles(cora_n3m6):
+    tr, state = cora_n3m6
     text = tr._impute_fn.lower(state).compile().as_text()
     assert text.startswith("HloModule jit__impute")
-    kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+    kernels = _kernel_calls(text)
     assert any("sim_topk" in line for line in kernels)
     assert any("sage_aggregate" in line for line in kernels)
+
+
+@pytest.mark.parametrize("program,module,calls", [
+    ("_local_fn", "jit__local_rounds", 1),
+    ("_eval_fn", "jit__evaluate", 1),
+    ("_impute_fn", "jit__impute", 2),
+])
+def test_the_rounds_read_layer_1_from_the_batch_cache(cora_n3m6, program, module,
+                                                       calls):
+    tr, state = cora_n3m6
+    assert state.batch.prop is not None
+    args = {"_local_fn": (state.params, state.opt_state, state.batch),
+            "_eval_fn": (state.params, state.batch),
+            "_impute_fn": (state,)}[program]
+    text = getattr(tr, program).lower(*args).compile().as_text()
+    assert text.startswith(f"HloModule {module}")
+    sage = [line for line in _kernel_calls(text) if "sage" in line]
+    assert len(sage) == calls, sage

@@ -123,6 +123,7 @@ def compiled(small):
         "_agg_fn": tr._agg_fn.lower(st.params, round=0, mask=None),
         "_eval_fn": tr._eval_fn.lower(st.params, st.batch),
         "_impute_fn": tr._impute_fn.lower(st),
+        "_propagate_fn": tr._propagate_fn.lower(st.batch),
     }
 
 
@@ -130,7 +131,8 @@ def compiled(small):
     ("_local_fn", "jit__local_rounds", {"local_train"}),
     ("_agg_fn", "jit__aggregate", {"aggregate"}),
     ("_eval_fn", "jit__evaluate", {"evaluate"}),
-    ("_impute_fn", "jit__impute", {"generator", "sim_topk", "patch"})])
+    ("_impute_fn", "jit__impute", {"generator", "sim_topk", "patch", "propagate"}),
+    ("_propagate_fn", "jit__propagate", {"propagate"})])
 def test_compiled_programs_carry_their_scopes(compiled, fn, module, want):
     text = compiled[fn].compile().as_text()
     assert text.startswith(f"HloModule {module},")
@@ -139,5 +141,5 @@ def test_compiled_programs_carry_their_scopes(compiled, fn, module, want):
     # A program carries its own scopes only: the readers split device time
     # by them.
     others = {"local_train", "aggregate", "evaluate", "generator", "sim_topk",
-              "patch"} - want
+              "patch", "propagate"} - want
     assert not (others & found)
