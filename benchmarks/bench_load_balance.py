@@ -17,6 +17,7 @@ devices exist.
 """
 from __future__ import annotations
 
+import dataclasses
 import os
 
 if __name__ == "__main__":  # must precede the first jax import
@@ -112,8 +113,8 @@ def bench_gossip_aggregation(fast: bool = False):
             "cross_server_bytes_per_round":
                 gossip.allreduce_bytes_per_round(pb, n)}}
         for k in ks:
-            tr_g = make_spreadfgl_gossip(cfg, batch, num_servers=n,
-                                         gossip_every=k, edge_mesh=mesh)
+            tr_g = make_spreadfgl_gossip(dataclasses.replace(cfg, gossip_every=k),
+                                         batch, num_servers=n, edge_mesh=mesh)
             state_g = tr_g.init(jax.random.key(0), batch)
             t_ex = timeit(lambda: tr_g.aggregate(state_g.params, round=k - 1),
                           iters=iters)
@@ -145,7 +146,8 @@ def bench_gossip_aggregation(fast: bool = False):
             ("dense_neighbor", lambda: make_spreadfgl(
                 cfg, batch, num_servers=n_conv, edge_mesh=mesh))]
     runs += [(f"gossip_K{k}", lambda k=k: make_spreadfgl_gossip(
-        cfg, batch, num_servers=n_conv, gossip_every=k, edge_mesh=mesh))
+        dataclasses.replace(cfg, gossip_every=k), batch, num_servers=n_conv,
+        edge_mesh=mesh))
         for k in ks]
     for name, make in runs:
         _, hist = make().fit(jax.random.key(0), batch, rounds=rounds)
@@ -173,7 +175,8 @@ def bench_impl_sweep(fast: bool = False):
     iters = 2 if fast else 5
     out = {"backend": jax.default_backend()}
     for impl in impls:
-        tr = make_spreadfgl(cfg, batch, num_servers=3, kernel_impl=impl)
+        tr = make_spreadfgl(dataclasses.replace(cfg, kernel_impl=impl), batch,
+                            num_servers=3)
         state = tr.init(jax.random.key(0), batch)
         t = timeit(lambda: tr._impute_fn(state), iters=iters)
         out[impl] = {"imputation_round_us": t}

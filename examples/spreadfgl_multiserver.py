@@ -53,7 +53,8 @@ def main():
           f"mean client label entropy={ent.mean():.3f} nats")
     cfg = FGLConfig(hidden_dim=32, local_rounds=4, imputation_interval=2,
                     top_k_links=4, aug_max=12, kernel_impl=args.impl,
-                    participation=args.participation)
+                    participation=args.participation,
+                    gossip_every=args.gossip_every)
 
     # The [N] server axis shards across whatever devices exist (size-1 mesh on
     # a single-device host — identical numbers, no sharding). Every method is
@@ -67,8 +68,7 @@ def main():
         "SpreadFGL (3 servers, ring)": registry.build(
             "SpreadFGL", cfg, batch, num_servers=3, edge_mesh=mesh),
         f"SpreadFGL-gossip (K={args.gossip_every})": registry.build(
-            "spreadfgl_gossip", cfg, batch, num_servers=3,
-            gossip_every=args.gossip_every, edge_mesh=mesh),
+            "spreadfgl_gossip", cfg, batch, num_servers=3, edge_mesh=mesh),
     }
     print(f"{'method':30s} {'best ACC':>9s} {'best F1':>9s} {'final loss':>11s}")
     for name, tr in methods.items():

@@ -152,14 +152,8 @@ class FGLTrainer:
                  *, topology: Optional[strategies.Topology] = None,
                  aggregator: Optional[strategies.Aggregator] = None,
                  imputation: Optional[strategies.ImputationStrategy] = None,
-                 kernel_impl: Optional[str] = None,
-                 participation: Optional[float] = None,
                  use_negative_sampling: bool = True, use_assessor: bool = True,
                  edge_mesh=None):
-        if kernel_impl is not None:       # constructor override wins over cfg
-            cfg = dataclasses.replace(cfg, kernel_impl=kernel_impl)
-        if participation is not None:     # same: ctor override wins over cfg
-            cfg = dataclasses.replace(cfg, participation=float(participation))
         if cfg.kernel_impl not in imputation_lib.KERNEL_IMPLS:
             raise ValueError(f"unknown kernel_impl {cfg.kernel_impl!r}; "
                              f"expected one of {imputation_lib.KERNEL_IMPLS}")
@@ -193,14 +187,7 @@ class FGLTrainer:
         self.n_local = batch.n_local_max
         self.use_ns = use_negative_sampling
         self.use_assessor = use_assessor
-        self.participation = float(cfg.participation)
-        # Partial participation draws from its OWN key stream, derived from
-        # cfg.seed and folded with the absolute round index: enabling ρ < 1
-        # never perturbs the training key threaded through FGLState (ρ = 1
-        # histories stay bit-identical), and the round-t mask is a pure
-        # function of (seed, t) — a checkpoint restored mid-run reproduces
-        # the participation schedule exactly, like the imputation and gossip
-        # schedules.
+        # The participation mask's own key stream (see `_schedule`).
         self._part_key = jax.random.fold_in(jax.random.key(cfg.seed), 0x9A57)
         self.opt = Adam(lr=cfg.lr_classifier)
         self.gen_opt = Adam(lr=cfg.lr_generator)
@@ -209,13 +196,10 @@ class FGLTrainer:
             raise ValueError(f"N={self.n_servers} servers must divide across the "
                              f"{edge_mesh.size}-device edge mesh")
         self._local_fn = jax.jit(self._local_rounds)
-        # Round-scheduled aggregators (GossipAggregator) expose a `period`;
-        # step() passes the canonicalized phase (`_agg_phase`) as a STATIC
-        # arg, so jit compiles exactly 2 variants — exchange and skip — and
-        # non-exchange rounds lower to zero cross-server collectives.
-        # Unscheduled aggregators have period 1.
-        self._agg_period = max(1, int(getattr(self.aggregator, "period", 1)))
-        self._agg_fn = jax.jit(self._aggregate, static_argnames=("round",))
+        # The schedule's flag is a STATIC arg: at most 2 compiled variants
+        # (exchange/skip, flush/skip), and skip rounds lower to no
+        # cross-server collectives.
+        self._agg_fn = jax.jit(self._aggregate, static_argnames=("flag",))
         self._impute_fn = _ForwardingJit(self._impute)
         # step()'s own call: the generator state and the batch's cache
         # (arguments 1 and 2) are donated
@@ -325,78 +309,42 @@ class FGLTrainer:
 
     # -- aggregation (strategy) ----------------------------------------------
 
-    def _aggregate(self, params, *, round=0, mask=None):
+    def _aggregate(self, params, *, flag=0, weights=None):
         """The Aggregator's call, under the ``aggregate`` scope whatever the
         strategy (FedAvg, Eq. 16, gossip, async)."""
         with jax.named_scope("aggregate"):
             return self.aggregator.aggregate(
                 params, adj=self.adj_servers, num_servers=self.n_servers,
-                m_per=self.m_per, round=round, mask=mask)
+                m_per=self.m_per, flag=flag, weights=weights)
 
-    def _agg_phase(self, t: int) -> int:
-        """Canonical static phase for the jitted aggregation call.
+    def _schedule(self, t: int) -> Tuple[int, Optional[jnp.ndarray]]:
+        """Round ``t``'s aggregation decision: ``(flag, weights)``.
 
-        Only two behaviors exist — exchange round or skip round — so the
-        phase is canonicalized to ``period - 1`` (exchange) or ``0`` (skip):
-        exactly 2 compiled variants regardless of K, instead of one cache
-        entry per distinct ``t % period``.
-
-        Buffered aggregators (:class:`strategies.AsyncAggregator`) expose a
-        ``phase(t, m)`` hook instead of a fixed period — their flush schedule
-        is data-independent but not periodic. The hook still returns only
-        0/1, so jit still sees exactly 2 variants.
+        The aggregator's own schedule, with the [M] 0/1 participation mask
+        of ``cfg.participation < 1`` multiplied into its weights: a client
+        sampled out contributes zero weight even if its (stale) update sits
+        in an async buffer. The mask draws from its OWN key stream, cfg.seed
+        folded with a salt and then with t, never from the training key in
+        ``FGLState``: ρ < 1 perturbs no other draw, and the mask is a pure
+        function of (seed, t), like the aggregator's schedule, so a state
+        restored mid-run resumes both exactly. Its shape is a static [M]
+        every round (exactly ceil(ρ·M) participants), so the jitted
+        aggregation compiles one weighted variant. At ρ = 1 with a
+        synchronous aggregator the weights are None: no array is built or
+        transferred.
         """
-        hook = getattr(self.aggregator, "phase", None)
-        if hook is not None:
-            return int(hook(t, self.m))
-        p = self._agg_period
-        return p - 1 if (t + 1) % p == 0 else 0
+        flag, weights = self.aggregator.schedule(t, self.m)
+        if self.cfg.participation < 1.0:
+            key = jax.random.fold_in(self._part_key, t)
+            mask = strategies.participation_mask(key, self.m, self.cfg.participation)
+            weights = mask if weights is None else weights * mask
+        return flag, weights
 
-    def _agg_mask(self, t: int):
-        """The [M] weight vector of round ``t``'s aggregation, or None.
-
-        Composes the two per-round weight sources: the participation mask
-        (ρ < 1) and, for buffered aggregators exposing ``round_weights(t,
-        m)``, the staleness-discount weights of the flush. Both are pure
-        functions of (cfg.seed, t), so the composition is too. A client
-        sampled out by ρ < 1 contributes zero weight even if its (stale)
-        update sits in the buffer.
-        """
-        mask = self._participation_mask(t)
-        hook = getattr(self.aggregator, "round_weights", None)
-        if hook is None:
-            return mask
-        weights = hook(t, self.m)
-        if weights is None or mask is None:
-            return weights if weights is not None else mask
-        return weights * mask
-
-    def _participation_mask(self, t: int):
-        """[M] 0/1 participation mask of round ``t``, or None at ρ = 1.
-
-        None (full participation) routes the aggregators onto their exact
-        unmasked code paths, so ρ = 1 reproduces pre-participation fixed-seed
-        goldens bit-identically. At ρ < 1 the mask has a static [M] shape
-        every round (exactly ceil(ρ·M) participants, never a gather/resize),
-        so the jitted aggregation compiles exactly one masked variant.
-        """
-        if self.participation >= 1.0:
-            return None
-        key = jax.random.fold_in(self._part_key, t)
-        return strategies.participation_mask(key, self.m, self.participation)
-
-    def aggregate(self, params: PyTree, *, round: int = 0, mask=None) -> PyTree:
-        """Apply this trainer's Aggregator to stacked client classifiers.
-
-        ``round`` matters only for round-scheduled aggregators (gossip every
-        K); it is canonicalized to the exchange/skip phase before the jitted
-        call. ``mask`` is an optional [M] participation mask (``step()``
-        passes the round's sampled mask when ``cfg.participation < 1``).
-        """
-        if mask is None:
-            mask = self._agg_mask(int(round))
-        return self._agg_fn(params, round=self._agg_phase(int(round)),
-                            mask=mask)
+    def aggregate(self, params: PyTree, *, round: int = 0) -> PyTree:
+        """Apply this trainer's Aggregator to stacked client classifiers,
+        with round ``round``'s schedule (``_schedule``)."""
+        flag, weights = self._schedule(int(round))
+        return self._agg_fn(params, flag=flag, weights=weights)
 
     # -- imputation helpers shared by the strategies --------------------------
 
@@ -604,14 +552,10 @@ class FGLTrainer:
                                             as_params=None, as_opt=None,
                                             batch=state.batch.replace(prop=None)),
                         gen, state.batch.prop)
-            # The gossip phase, the participation mask, and the async flush
-            # schedule are pure functions of the absolute round, so a state
-            # restored mid-interval (or mid-buffer) resumes every schedule
-            # exactly where the checkpoint left it.
-            with span("fgl.schedule"):
-                phase, mask = self._agg_phase(t), self._agg_mask(t)
+            with span("fgl.schedule"):  # a pure function of the absolute round
+                flag, weights = self._schedule(t)
             with span("fgl.aggregate"):
-                state.params = self._agg_fn(state.params, round=phase, mask=mask)
+                state.params = self._agg_fn(state.params, flag=flag, weights=weights)
             with span("fgl.evaluate"):
                 loss, acc, f1 = self._eval_fn(state.params, state.batch)
             state.round = t + 1

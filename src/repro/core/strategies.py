@@ -11,14 +11,12 @@ implementations here:
 - :class:`Aggregator` — how client classifiers are combined each round
   (FedAvg, Eq. 16 neighbor aggregation, gossip-SGD over the edge mesh,
   FedBuff-style buffered async aggregation, identity for purely local
-  training). FedGTA-style variants swap this axis. Aggregators that
-  schedule cross-server exchanges (gossip every K rounds) advertise a
-  ``period``; the engine passes ``round`` canonicalized to the
-  exchange/skip phase so jit sees exactly 2 static variants. Buffered
-  aggregators (:class:`AsyncAggregator`) instead expose ``phase``/
-  ``round_weights`` hooks — the flush schedule and the staleness weights
-  are pure functions of ``(cfg.seed, round)``, so jit still sees exactly
-  2 static variants (flush / skip) and save/resume mid-buffer is exact.
+  training). FedGTA-style variants swap this axis. Each aggregator's
+  ``schedule(t, M)`` gives the round's static flag (exchange/skip for
+  gossip every K rounds, flush/skip for buffered async) and its [M] client
+  weights; both are pure functions of ``(cfg.seed, round)``, so jit sees
+  at most 2 static variants and save/resume mid-schedule is exact. Every
+  aggregator reduces through one weighted per-server sum.
 - :class:`ImputationStrategy` — what happens on the every-K graph-fixing
   round (the SpreadFGL generator round, FedSage+'s local neighbor
   generation, or nothing).
@@ -31,7 +29,7 @@ jax state — per-round state threads through ``FGLState``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Any, Optional, Protocol, runtime_checkable
+from typing import Any, Optional, Protocol, Tuple, runtime_checkable
 
 import jax
 import jax.numpy as jnp
@@ -137,56 +135,85 @@ def participation_mask(key: jax.Array, num_clients: int, rho: float) -> jnp.ndar
     return jnp.zeros((num_clients,), jnp.float32).at[perm[:k]].set(1.0)
 
 
-def _masked_server_mean(leaf: jnp.ndarray, mask_g: jnp.ndarray,
-                        num_servers: int, m_per: int) -> jnp.ndarray:
-    """Participation-weighted per-server mean over a grouped leaf.
+def _server_sum(leaf: jnp.ndarray, weights: Optional[jnp.ndarray],
+                num_servers: int, m_per: int) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The weighted sum of each server's clients in one stacked [M, ...]
+    leaf, and each server's total weight: ``([N, ...], [N])``.
 
-    ``mask_g`` is the [N, m_per] participation mask. A server whose covered
-    clients ALL sit out this round falls back to the plain unweighted mean —
-    the edge server re-broadcasts the weights it already holds rather than
-    dividing by zero.
+    ``weights`` is the round's [M] client weights; None means unit weights,
+    a constant of the trace, so the multiply folds away and the sum is the
+    plain per-server client sum.
     """
+    if weights is None:
+        weights = jnp.ones((num_servers * m_per,), jnp.float32)
+    w = weights.reshape(num_servers, m_per)
     grouped = leaf.reshape((num_servers, m_per) + leaf.shape[1:])
-    shaped = mask_g.reshape((num_servers, m_per) + (1,) * (leaf.ndim - 1))
-    num = jnp.sum(grouped * shaped, axis=1)
-    den = jnp.sum(mask_g, axis=1).reshape((num_servers,) + (1,) * (leaf.ndim - 1))
-    plain = jnp.sum(grouped, axis=1) / m_per
-    return jnp.where(den > 0, num / jnp.maximum(den, 1.0), plain)
+    return (jnp.sum(grouped * w.reshape(w.shape + (1,) * (leaf.ndim - 1)), axis=1),
+            jnp.sum(w, axis=1))
+
+
+def _per_server(v: jnp.ndarray, leaf: jnp.ndarray) -> jnp.ndarray:
+    """A leading-axis vector shaped to broadcast against ``leaf``."""
+    return v.reshape(v.shape + (1,) * (leaf.ndim - 1))
+
+
+def _divide(num: jnp.ndarray, den: jnp.ndarray, fallback: jnp.ndarray) -> jnp.ndarray:
+    """``num / den`` per server, and ``fallback`` where ``den`` is zero."""
+    den = _per_server(den, num)
+    return jnp.where(den > 0, num / jnp.where(den > 0, den, 1.0), fallback)
+
+
+def _server_mean(leaf: jnp.ndarray, weights: Optional[jnp.ndarray],
+                 num_servers: int, m_per: int) -> jnp.ndarray:
+    """[N, ...] weighted mean of each server's clients. A server whose
+    clients all carry zero weight falls back to its plain mean: the edge
+    server re-broadcasts the weights it already holds."""
+    total, weight = _server_sum(leaf, weights, num_servers, m_per)
+    plain, _ = _server_sum(leaf, None, num_servers, m_per)
+    return _divide(total, weight, plain / m_per)
 
 
 @runtime_checkable
 class Aggregator(Protocol):
     """Combine stacked [M] client classifiers once per global round.
 
-    ``round`` is the global round index; the engine canonicalizes it before
-    the jitted call (``FGLTrainer._agg_phase``: ``period - 1`` on exchange
-    rounds, ``0`` otherwise) — a static Python int, so round-scheduled
-    aggregators compile exactly two variants, not one per round. Aggregators
-    without a schedule (``period`` 1) ignore it.
+    ``schedule(t, M)`` is the round's aggregation decision, made on the
+    host: ``(flag, weights)``. ``flag`` is a static 0/1 (exchange or skip
+    for gossip, flush or skip for async, a constant for the rest), so the
+    jitted aggregation compiles at most two variants. ``weights`` is an [M]
+    float vector of client weights, or None for unit weights. Both must be
+    pure functions of the aggregator's settings and ``t``, so a state
+    restored at round t resumes the schedule exactly.
 
-    ``mask`` is the optional [M] participation mask of the round
-    (:func:`participation_mask`); every mean becomes mask-weighted so
-    non-participating clients contribute nothing. ``mask=None`` means full
-    participation and MUST take the exact unmasked code path — the engine
-    passes None whenever ``cfg.participation == 1`` so fixed-seed goldens
-    stay bit-identical.
+    ``aggregate`` takes the round's ``flag`` and ``weights``: the engine
+    (``FGLTrainer._schedule``) multiplies the participation mask of
+    ``cfg.participation < 1`` into the weights, so a client sampled out
+    contributes nothing. Every aggregator reduces through one arithmetic
+    path, :func:`_server_sum`; with ``weights`` None its unit weights fold
+    away in the compiled program.
     """
 
+    def schedule(self, t: int, num_clients: int
+                 ) -> Tuple[int, Optional[jnp.ndarray]]: ...
+
     def aggregate(self, params: PyTree, *, adj: jnp.ndarray,
-                  num_servers: int, m_per: int, round: int = 0,
-                  mask: Optional[jnp.ndarray] = None) -> PyTree: ...
+                  num_servers: int, m_per: int, flag: int = 0,
+                  weights: Optional[jnp.ndarray] = None) -> PyTree: ...
 
 
 @dataclasses.dataclass(frozen=True)
 class IdentityAggregator:
     """No aggregation: clients keep their own weights (LocalFGL, Sec. IV-A).
 
-    ``mask`` is accepted and ignored: with no cross-client mixing there is
-    nothing for partial participation to gate — a non-participating client
-    keeping its own weights is exactly what identity already does.
+    ``weights`` are accepted and ignored: with no cross-client mixing there
+    is nothing for partial participation to gate — a non-participating
+    client keeping its own weights is exactly what identity already does.
     """
 
-    def aggregate(self, params, *, adj, num_servers, m_per, round=0, mask=None):
+    def schedule(self, t, num_clients):
+        return 0, None
+
+    def aggregate(self, params, *, adj, num_servers, m_per, flag=0, weights=None):
         return params
 
 
@@ -194,22 +221,17 @@ class IdentityAggregator:
 class FedAvgAggregator:
     """Per-server FedAvg (McMahan et al.): mean over covered clients,
     broadcast back — classic FGL's single aggregation point when N = 1
-    (FedGL, Sec. III-B). With a participation ``mask`` the mean runs over
-    the round's participating clients only (all-out servers re-broadcast
-    their plain mean, see :func:`_masked_server_mean`)."""
+    (FedGL, Sec. III-B). With ``weights`` the mean runs over the round's
+    weighted clients (all-out servers re-broadcast their plain mean, see
+    :func:`_server_mean`)."""
 
-    def aggregate(self, params, *, adj, num_servers, m_per, round=0, mask=None):
-        if mask is None:
-            def agg(leaf):
-                grouped = leaf.reshape((num_servers, m_per) + leaf.shape[1:])
-                w = jnp.sum(grouped, axis=1) / m_per
-                return jnp.repeat(w, m_per, axis=0)
-        else:
-            mask_g = mask.reshape(num_servers, m_per)
+    def schedule(self, t, num_clients):
+        return 0, None
 
-            def agg(leaf):
-                w = _masked_server_mean(leaf, mask_g, num_servers, m_per)
-                return jnp.repeat(w, m_per, axis=0)
+    def aggregate(self, params, *, adj, num_servers, m_per, flag=0, weights=None):
+        def agg(leaf):
+            w = _server_mean(leaf, weights, num_servers, m_per)
+            return jnp.repeat(w, m_per, axis=0)
         return jax.tree.map(agg, params)
 
 
@@ -220,42 +242,34 @@ class NeighborAggregator:
 
     W_j = sum_r a_rj * sum_i W_(r,i) / sum_r a_rj M_r — the SpreadFGL rule
     that removes the single aggregation point. :class:`GossipAggregator`
-    computes the identical update on exchange rounds but amortizes the
-    cross-server traffic over K rounds; with ``every_k=1`` on the same
-    adjacency the two are numerically interchangeable
-    (``tests/test_gossip.py`` pins the allclose).
+    computes the identical update on exchange rounds at full participation
+    but amortizes the cross-server traffic over K rounds; with
+    ``every_k=1`` on the same adjacency the two agree to float32 tolerance
+    (``tests/test_gossip.py``). Under partial participation they differ:
+    Eq. 16 weights each server by its participating count, gossip mixes
+    equal server means.
 
-    With a participation ``mask``, Eq. 16's client count M_r becomes the
-    round's participating count m̃_r (mask-weighted sums in both numerator
-    and denominator); a neighborhood that entirely sat out falls back to the
+    With ``weights``, Eq. 16's client count M_r becomes the round's
+    participating count m̃_r (weighted sums in both numerator and
+    denominator); a neighborhood that entirely sat out falls back to the
     plain Eq. 16 mix.
     """
 
-    def aggregate(self, params, *, adj, num_servers, m_per, round=0, mask=None):
-        if mask is None:
-            def agg(leaf):
-                grouped = leaf.reshape((num_servers, m_per) + leaf.shape[1:])
-                client_sum = jnp.sum(grouped, axis=1)              # [N, ...]
-                num = jnp.einsum("rj,r...->j...", adj, client_sum)
-                den = jnp.sum(adj, axis=0) * m_per                 # [N]
-                w = num / den.reshape((num_servers,) + (1,) * (leaf.ndim - 1))
-                return jnp.repeat(w, m_per, axis=0)
-        else:
-            mask_g = mask.reshape(num_servers, m_per)
-            counts = jnp.sum(mask_g, axis=1)                       # m̃_r [N]
+    def schedule(self, t, num_clients):
+        return 0, None
 
-            def agg(leaf):
-                grouped = leaf.reshape((num_servers, m_per) + leaf.shape[1:])
-                shaped = mask_g.reshape((num_servers, m_per) + (1,) * (leaf.ndim - 1))
-                tail = (1,) * (leaf.ndim - 1)
-                num = jnp.einsum("rj,r...->j...", adj,
-                                 jnp.sum(grouped * shaped, axis=1))
-                den = jnp.einsum("r,rj->j", counts, adj).reshape((num_servers,) + tail)
-                plain_num = jnp.einsum("rj,r...->j...", adj, jnp.sum(grouped, axis=1))
-                plain_den = (jnp.sum(adj, axis=0) * m_per).reshape((num_servers,) + tail)
-                w = jnp.where(den > 0, num / jnp.maximum(den, 1.0),
-                              plain_num / plain_den)
-                return jnp.repeat(w, m_per, axis=0)
+    def aggregate(self, params, *, adj, num_servers, m_per, flag=0, weights=None):
+        def mix(leaf, w):
+            """Eq. 16's numerator and [N] denominator: sums over neighbors r."""
+            total, weight = _server_sum(leaf, w, num_servers, m_per)
+            return (jnp.einsum("rj,r...->j...", adj, total),
+                    jnp.sum(adj * weight[:, None], axis=0))
+
+        def agg(leaf):
+            num, den = mix(leaf, weights)
+            plain_num, plain_den = mix(leaf, None)
+            w = _divide(num, den, plain_num / _per_server(plain_den, plain_num))
+            return jnp.repeat(w, m_per, axis=0)
         return jax.tree.map(agg, params)
 
 
@@ -284,13 +298,14 @@ class GossipAggregator:
     Equivalences, both pinned in ``tests/test_gossip.py``:
 
     - ``GossipAggregator(every_k=1)`` == :class:`NeighborAggregator` on the
-      same adjacency (ring or custom), to float32 tolerance.
+      same adjacency (ring or custom) at full participation, to float32
+      tolerance.
     - On non-exchange rounds it equals :class:`FedAvgAggregator` applied
       per server.
 
-    The gossip round-phase is ``state.round % every_k`` — a pure function
-    of the checkpointed round, so save/resume mid-interval keeps the
-    exchange schedule intact.
+    The schedule's flag is 1 on rounds with ``(t + 1) % every_k == 0`` — a
+    pure function of the checkpointed round, so save/resume mid-interval
+    keeps the exchange schedule intact.
     """
 
     topology: str = "ring"        # "ring" | "adjacency"
@@ -304,28 +319,17 @@ class GossipAggregator:
         if self.every_k < 1:
             raise ValueError(f"every_k must be >= 1, got {self.every_k}")
 
-    @property
-    def period(self) -> int:
-        """Exchange schedule length; the engine passes ``round`` mod this."""
-        return self.every_k
+    def schedule(self, t, num_clients):
+        return int((t + 1) % self.every_k == 0), None
 
-    def aggregate(self, params, *, adj, num_servers, m_per, round=0, mask=None):
-        if mask is None:
-            def server_mean(leaf):
-                grouped = leaf.reshape((num_servers, m_per) + leaf.shape[1:])
-                return jnp.sum(grouped, axis=1) / m_per
-        else:
-            # Participation gates the edge-client leg only: the per-server
-            # mean runs over participating clients (all-out servers keep
-            # their plain mean); the cross-server exchange is unchanged —
-            # servers always gossip whatever they aggregated this round.
-            mask_g = mask.reshape(num_servers, m_per)
-
-            def server_mean(leaf):
-                return _masked_server_mean(leaf, mask_g, num_servers, m_per)
-
-        w = jax.tree.map(server_mean, params)                  # [N, ...]
-        if num_servers > 1 and (round + 1) % self.every_k == 0:
+    def aggregate(self, params, *, adj, num_servers, m_per, flag=0, weights=None):
+        # Participation gates the edge-client leg only: the per-server mean
+        # runs over weighted clients (all-out servers keep their plain
+        # mean); the cross-server exchange is unchanged — servers always
+        # gossip whatever they aggregated this round.
+        w = jax.tree.map(                                          # [N, ...]
+            lambda leaf: _server_mean(leaf, weights, num_servers, m_per), params)
+        if num_servers > 1 and flag:
             w = self._exchange(w, adj, num_servers)
         return jax.tree.map(lambda leaf: jnp.repeat(leaf, m_per, axis=0), w)
 
@@ -535,38 +539,26 @@ class AsyncAggregator:
         return (self.seed, num_clients, self.buffer_size, self.delay_dist,
                 self.max_delay, self.dropout_rate)
 
-    def phase(self, round: int, num_clients: int) -> int:
-        """1 on flush rounds, 0 otherwise — the static arg of the jitted
-        aggregation call, so jit compiles exactly 2 variants."""
-        flush, _ = _async_schedule(self._spec(num_clients), round)
-        return int(flush)
+    def schedule(self, t, num_clients):
+        """``(1, [M] float32 staleness weights)`` on flush rounds, ``(0,
+        None)`` otherwise."""
+        flush, weights = _async_schedule(self._spec(num_clients), t)
+        return int(flush), None if weights is None else jnp.asarray(weights)
 
-    def round_weights(self, round: int, num_clients: int):
-        """[M] float32 staleness weights on flush rounds, else None."""
-        _, weights = _async_schedule(self._spec(num_clients), round)
-        return None if weights is None else jnp.asarray(weights)
-
-    def aggregate(self, params, *, adj, num_servers, m_per, round=0, mask=None):
-        """``round`` is the flush phase (1 = flush); ``mask`` carries the
-        [M] staleness weights (zero = not buffered). Skip rounds are
+    def aggregate(self, params, *, adj, num_servers, m_per, flag=0, weights=None):
+        """``flag`` is the flush (1) or skip (0) of the round; ``weights``
+        the [M] staleness weights (zero = not buffered). Skip rounds are
         identity. ``adj`` is unused: like :class:`FedAvgAggregator` the
         flush is per-server — cross-server spread still happens through
         the shared imputation round."""
-        if not round or mask is None:
+        if not flag:
             return params
-        mask_g = jnp.asarray(mask, jnp.float32).reshape(num_servers, m_per)
-        den = jnp.sum(mask_g, axis=1)                       # [N] total weight
 
         def agg(leaf):
-            grouped = leaf.reshape((num_servers, m_per) + leaf.shape[1:])
-            tail = (1,) * (leaf.ndim - 1)
-            shaped = mask_g.reshape((num_servers, m_per) + tail)
-            num = jnp.sum(grouped * shaped, axis=1)
-            den_s = den.reshape((num_servers,) + tail)
-            w = num / jnp.where(den_s > 0, den_s, 1.0)
-            keep = jnp.repeat(den > 0, m_per).reshape(
-                (num_servers * m_per,) + tail)
-            return jnp.where(keep, jnp.repeat(w, m_per, axis=0), leaf)
+            total, weight = _server_sum(leaf, weights, num_servers, m_per)
+            w = total / _per_server(jnp.where(weight > 0, weight, 1.0), total)
+            keep = jnp.repeat(weight > 0, m_per)
+            return jnp.where(_per_server(keep, leaf), jnp.repeat(w, m_per, axis=0), leaf)
         return jax.tree.map(agg, params)
 
 

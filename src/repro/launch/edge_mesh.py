@@ -91,7 +91,6 @@ def main() -> None:
         print(f"[edge-mesh] gossip aggregation: neighbor exchange every "
               f"{args.gossip_every} round(s) over the mesh")
         tr = make_spreadfgl_gossip(cfg, batch, num_servers=args.servers,
-                                   gossip_every=args.gossip_every,
                                    edge_mesh=mesh, sim_mesh=sim_mesh)
     else:
         tr = make_spreadfgl(cfg, batch, num_servers=args.servers,

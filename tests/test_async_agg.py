@@ -8,7 +8,7 @@ and the gossip phase already honor:
   stream — so enabling async aggregation never perturbs other randomness;
 - the buffer is a static [M] occupancy and the flush weights reach the
   jitted aggregation as a traced [M] vector, flush/skip being the only
-  static split;
+  static split (``schedule(t, M) -> (flag, weights)``);
 - the whole delay/buffer/staleness schedule is a pure function of the
   absolute round, so save/resume mid-buffer replays it exactly.
 
@@ -157,8 +157,9 @@ class TestSchedule:
     def test_b_equals_m_zero_delay_flushes_every_round_with_unit_weights(self):
         agg = S.AsyncAggregator(buffer_size=6, delay_dist="zero")
         for t in range(8):
-            assert agg.phase(t, 6) == 1
-            w = np.asarray(agg.round_weights(t, 6))
+            flag, w = agg.schedule(t, 6)
+            assert flag == 1
+            w = np.asarray(w)
             np.testing.assert_array_equal(w, np.ones(6, np.float32))
 
     @pytest.mark.parametrize("dist,drop", [("zero", 0.0), ("uniform", 0.0),
@@ -170,8 +171,8 @@ class TestSchedule:
                                 dropout_rate=drop, max_delay=4, seed=11)
         oracle = _schedule_oracle(11, 5, 3, dist, 4, drop, 24)
         for t, (flush, weights) in enumerate(oracle):
-            assert agg.phase(t, 5) == int(flush), t
-            got = agg.round_weights(t, 5)
+            flag, got = agg.schedule(t, 5)
+            assert flag == int(flush), t
             if weights is None:
                 assert got is None
             else:
@@ -184,7 +185,7 @@ class TestSchedule:
                                 dropout_rate=0.3, seed=5)
         seen_stale = set()
         for t in range(30):
-            w = agg.round_weights(t, 6)
+            _, w = agg.schedule(t, 6)
             if w is None:
                 continue
             w = np.asarray(w)
@@ -200,9 +201,9 @@ class TestSchedule:
         value the warm sequential walk produced."""
         agg = S.AsyncAggregator(buffer_size=2, delay_dist="uniform",
                                 dropout_rate=0.1, seed=9)
-        warm = [(agg.phase(t, 4), agg.round_weights(t, 4)) for t in range(20)]
+        warm = [agg.schedule(t, 4) for t in range(20)]
         S._ASYNC_SCHEDULES.clear()
-        cold_f, cold_w = agg.phase(17, 4), agg.round_weights(17, 4)
+        cold_f, cold_w = agg.schedule(17, 4)
         assert cold_f == warm[17][0]
         if warm[17][1] is None:
             assert cold_w is None
@@ -213,15 +214,15 @@ class TestSchedule:
     def test_phase_is_binary(self):
         agg = S.AsyncAggregator(buffer_size=3, delay_dist="geometric",
                                 dropout_rate=0.4, seed=2)
-        assert {agg.phase(t, 8) for t in range(40)} <= {0, 1}
+        assert {agg.schedule(t, 8)[0] for t in range(40)} <= {0, 1}
 
     def test_different_seeds_give_different_schedules(self):
         # Seeds 0 and 1 both flush on all 16 rounds under jax 0.9.0's
         # streams, so the pair proves nothing; seeds 0 and 2 diverge.
         a = S.AsyncAggregator(buffer_size=2, delay_dist="geometric", seed=0)
         b = S.AsyncAggregator(buffer_size=2, delay_dist="geometric", seed=2)
-        fa = [a.phase(t, 6) for t in range(16)]
-        fb = [b.phase(t, 6) for t in range(16)]
+        fa = [a.schedule(t, 6)[0] for t in range(16)]
+        fb = [b.schedule(t, 6)[0] for t in range(16)]
         assert fa != fb
 
 
@@ -247,12 +248,12 @@ class TestAsyncAggregatorUnit:
         with pytest.raises(ValueError, match="max_delay"):
             S.AsyncAggregator(buffer_size=1, max_delay=-2)
         with pytest.raises(ValueError, match="never fill"):
-            S.AsyncAggregator(buffer_size=9).phase(0, 4)
+            S.AsyncAggregator(buffer_size=9).schedule(0, 4)
 
     def test_skip_round_is_identity(self):
         params = self._params()
         agg = S.AsyncAggregator(buffer_size=4)
-        out = agg.aggregate(params, round=0, mask=None, **self._kw())
+        out = agg.aggregate(params, flag=0, weights=None, **self._kw())
         assert out is params
 
     def test_flush_is_hand_computed_weighted_mean(self):
@@ -261,7 +262,7 @@ class TestAsyncAggregatorUnit:
         params = self._params()
         w = jnp.asarray([1.0, 0.5, 0.0, 0.0], jnp.float32)
         agg = S.AsyncAggregator(buffer_size=2)
-        out = agg.aggregate(params, round=1, mask=w, **self._kw())
+        out = agg.aggregate(params, flag=1, weights=w, **self._kw())
         pw = np.asarray(params["w"])
         want0 = (1.0 * pw[0] + 0.5 * pw[1]) / 1.5
         np.testing.assert_allclose(np.asarray(out["w"])[0], want0, rtol=1e-6)
@@ -276,8 +277,8 @@ class TestAsyncAggregatorUnit:
         params = self._params()
         fedavg = S.FedAvgAggregator().aggregate(params, **self._kw())
         agg = S.AsyncAggregator(buffer_size=4)
-        out = agg.aggregate(params, round=1,
-                            mask=jnp.ones(4, jnp.float32), **self._kw())
+        out = agg.aggregate(params, flag=1,
+                            weights=jnp.ones(4, jnp.float32), **self._kw())
         for a, b in zip(jax.tree.leaves(fedavg), jax.tree.leaves(out)):
             a, b = np.asarray(a), np.asarray(b)
             np.testing.assert_array_equal(a.view(np.uint32), b.view(np.uint32))
@@ -288,9 +289,9 @@ class TestAsyncAggregatorUnit:
         params = self._params()
         w = jnp.asarray([1.0, 1.0, 0.5, 0.0], jnp.float32)
         agg = S.AsyncAggregator(buffer_size=2)
-        a = agg.aggregate(params, round=1, mask=w, adj=jnp.eye(self.N),
+        a = agg.aggregate(params, flag=1, weights=w, adj=jnp.eye(self.N),
                           num_servers=self.N, m_per=self.M_PER)
-        b = agg.aggregate(params, round=1, mask=w,
+        b = agg.aggregate(params, flag=1, weights=w,
                           adj=jnp.ones((self.N, self.N)),
                           num_servers=self.N, m_per=self.M_PER)
         for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
@@ -399,18 +400,21 @@ class TestEngineThreading:
         cfg = _sync_cfg(cfg, async_buffer=M, participation=0.5)
         tr = make_spreadfgl_async(cfg, batch, num_servers=1)
         t = 0   # B = M, zero delay: round 0 flushes with unit weights
-        part = np.asarray(tr._participation_mask(t))
-        flush = np.asarray(tr.aggregator.round_weights(t, tr.m))
-        np.testing.assert_array_equal(np.asarray(tr._agg_mask(t)),
-                                      part * flush)
+        part = np.asarray(S.participation_mask(
+            jax.random.fold_in(jax.random.fold_in(jax.random.key(cfg.seed), 0x9A57), t),
+            tr.m, 0.5))
+        flag, flush = tr.aggregator.schedule(t, tr.m)
+        assert flag == 1 and tr._schedule(t)[0] == 1
+        np.testing.assert_array_equal(np.asarray(tr._schedule(t)[1]),
+                                      part * np.asarray(flush))
 
     def test_agg_mask_none_on_skip_rounds(self, small):
         batch, cfg = small
         cfg = _sync_cfg(cfg, async_buffer=M, delay_dist="uniform", seed=4)
         tr = make_spreadfgl_async(cfg, batch, num_servers=1)
-        skip = [t for t in range(12) if tr._agg_phase(t) == 0]
+        skip = [t for t in range(12) if tr._schedule(t)[0] == 0]
         assert skip, "uniform delays must produce at least one skip round"
-        assert tr._agg_mask(skip[0]) is None
+        assert tr._schedule(skip[0])[1] is None
 
     def test_builder_validation(self, small):
         batch, cfg = small
@@ -432,7 +436,7 @@ class TestEngineThreading:
     @pytest.mark.parametrize("name,kw", [
         ("local", {}), ("fedavg_fusion", {}), ("fedsage_plus", {}),
         ("FedGL", {}), ("SpreadFGL", {"num_servers": 2}),
-        ("spreadfgl_gossip", {"num_servers": 2, "gossip_every": 2}),
+        ("spreadfgl_gossip", {"num_servers": 2}),
         ("spreadfgl_async", {"num_servers": 2}),
     ])
     def test_every_registered_method_trains_with_async_buffer_set(
@@ -442,7 +446,7 @@ class TestEngineThreading:
         every registry method still trains."""
         batch, cfg = small
         cfg = _sync_cfg(cfg, async_buffer=2, delay_dist="geometric",
-                        dropout_rate=0.1)
+                        dropout_rate=0.1, gossip_every=2)
         tr = registry.build(name, cfg, batch, **kw)
         _, hist = tr.fit(jax.random.key(0), batch, rounds=2)
         assert np.isfinite(hist["loss"]).all(), name

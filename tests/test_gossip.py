@@ -59,7 +59,7 @@ class TestAggregatorParity:
         dense = S.NeighborAggregator().aggregate(
             params, adj=adj, num_servers=n, m_per=m_per)
         gossiped = S.GossipAggregator(topology="ring", every_k=1).aggregate(
-            params, adj=adj, num_servers=n, m_per=m_per)
+            params, adj=adj, num_servers=n, m_per=m_per, flag=1)
         for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(gossiped)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-6, atol=1e-6)
@@ -75,7 +75,7 @@ class TestAggregatorParity:
         dense = S.NeighborAggregator().aggregate(
             params, adj=adj, num_servers=n, m_per=m_per)
         gossiped = S.GossipAggregator(topology="adjacency", every_k=1).aggregate(
-            params, adj=adj, num_servers=n, m_per=m_per)
+            params, adj=adj, num_servers=n, m_per=m_per, flag=1)
         for a, b in zip(jax.tree.leaves(dense), jax.tree.leaves(gossiped)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-6, atol=1e-6)
@@ -88,14 +88,15 @@ class TestAggregatorParity:
         agg = S.GossipAggregator(topology="ring", every_k=4)
         fedavg = S.FedAvgAggregator().aggregate(
             params, adj=adj, num_servers=n, m_per=m_per)
-        for phase in (0, 1, 2):    # exchange happens only at phase 3
+        for t in (0, 1, 2):    # exchange happens only at round 3
+            flag, _ = agg.schedule(t, n * m_per)
             skipped = agg.aggregate(params, adj=adj, num_servers=n,
-                                    m_per=m_per, round=phase)
+                                    m_per=m_per, flag=flag)
             for a, b in zip(jax.tree.leaves(fedavg), jax.tree.leaves(skipped)):
                 np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                            rtol=1e-6)
         exchanged = agg.aggregate(params, adj=adj, num_servers=n,
-                                  m_per=m_per, round=3)
+                                  m_per=m_per, flag=agg.schedule(3, n * m_per)[0])
         assert not np.allclose(np.asarray(exchanged["w"]),
                                np.asarray(fedavg["w"]), rtol=1e-6)
 
@@ -106,7 +107,7 @@ class TestAggregatorParity:
         params = stacked_params(jax.random.key(3), n * m_per)
         agg = S.GossipAggregator(topology="ring", every_k=1)
         out = agg.aggregate(params, adj=jnp.asarray(ring_adjacency(n)),
-                            num_servers=n, m_per=m_per)
+                            num_servers=n, m_per=m_per, flag=1)
         for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(out)):
             np.testing.assert_allclose(np.asarray(a).mean(0),
                                        np.asarray(b).mean(0), rtol=1e-5)
@@ -157,8 +158,8 @@ class TestEngineParity:
         batch, cfg = small
         _, dense = make_spreadfgl(cfg, batch, num_servers=2).fit(
             jax.random.key(0), batch, rounds=4)
-        _, gossiped = make_spreadfgl_gossip(cfg, batch, num_servers=2,
-                                            gossip_every=1).fit(
+        _, gossiped = make_spreadfgl_gossip(
+            dataclasses.replace(cfg, gossip_every=1), batch, num_servers=2).fit(
             jax.random.key(0), batch, rounds=4)
         for k in ("loss", "acc", "f1"):
             np.testing.assert_allclose(gossiped[k], dense[k], rtol=1e-4,
@@ -167,11 +168,12 @@ class TestEngineParity:
     def test_registry_builds_gossip_method(self, small):
         batch, cfg = small
         assert "spreadfgl_gossip" in registry.names()
-        tr = registry.build("spreadfgl_gossip", cfg, batch, num_servers=2,
-                            gossip_every=3)
+        tr = registry.build("spreadfgl_gossip",
+                            dataclasses.replace(cfg, gossip_every=3), batch,
+                            num_servers=2)
         assert isinstance(tr.aggregator, S.GossipAggregator)
         assert tr.aggregator.every_k == 3
-        assert tr._agg_period == 3
+        assert [tr._schedule(t) for t in range(6)] == [(0, None), (0, None), (1, None)] * 2
 
     def test_gossip_every_defaults_to_cfg(self, small):
         batch, cfg = small
@@ -184,8 +186,8 @@ class TestEngineParity:
         batch, cfg = small
         _, dense = make_spreadfgl(cfg, batch, num_servers=2).fit(
             jax.random.key(0), batch, rounds=2)
-        _, gossiped = make_spreadfgl_gossip(cfg, batch, num_servers=2,
-                                            gossip_every=2).fit(
+        _, gossiped = make_spreadfgl_gossip(
+            dataclasses.replace(cfg, gossip_every=2), batch, num_servers=2).fit(
             jax.random.key(0), batch, rounds=2)
         assert not np.allclose(gossiped["loss"], dense["loss"], rtol=1e-6)
 
@@ -196,7 +198,8 @@ class TestResumeMidInterval:
         run re-enters the exchange schedule at phase round%K (round 3 is an
         exchange round — only hit if the phase survives the checkpoint)."""
         batch, cfg = small
-        tr = make_spreadfgl_gossip(cfg, batch, num_servers=2, gossip_every=2)
+        tr = make_spreadfgl_gossip(dataclasses.replace(cfg, gossip_every=2), batch,
+                                   num_servers=2)
         _, full = tr.fit(jax.random.key(0), batch, rounds=6)
 
         state, first = tr.fit(jax.random.key(0), batch, rounds=3)
@@ -233,8 +236,8 @@ def test_gossip_exchange_crosses_edge_mesh_subprocess():
         mesh = Mesh(jax.devices()[:4], ("edge",))
         meshed = S.GossipAggregator(topology="ring", every_k=1, mesh=mesh)
         hosted = S.GossipAggregator(topology="ring", every_k=1)
-        a = meshed.aggregate(params, adj=adj, num_servers=n, m_per=m_per)
-        b = hosted.aggregate(params, adj=adj, num_servers=n, m_per=m_per)
+        a = meshed.aggregate(params, adj=adj, num_servers=n, m_per=m_per, flag=1)
+        b = hosted.aggregate(params, adj=adj, num_servers=n, m_per=m_per, flag=1)
         np.testing.assert_allclose(np.asarray(a["w"]), np.asarray(b["w"]),
                                    rtol=1e-6)
         np.testing.assert_allclose(np.asarray(a["w"]).mean(0),
@@ -242,7 +245,7 @@ def test_gossip_exchange_crosses_edge_mesh_subprocess():
         # Block-sharded: 4 servers on a 2-device mesh (2 servers per shard).
         mesh2 = Mesh(jax.devices()[:2], ("edge",))
         blocked = S.GossipAggregator(topology="ring", every_k=1, mesh=mesh2)
-        c = blocked.aggregate(params, adj=adj, num_servers=n, m_per=m_per)
+        c = blocked.aggregate(params, adj=adj, num_servers=n, m_per=m_per, flag=1)
         np.testing.assert_allclose(np.asarray(c["w"]), np.asarray(b["w"]),
                                    rtol=1e-6)
         print("GOSSIP-MESH-OK")

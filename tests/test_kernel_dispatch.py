@@ -118,17 +118,11 @@ class TestKernelImplKnob:
         with pytest.raises(ValueError, match="kernel_impl"):
             make_fedgl(dataclasses.replace(cfg, kernel_impl="triton"), batch)
 
-    def test_constructor_override_wins_over_cfg(self, small):
-        batch, cfg = small
-        tr = make_fedgl(cfg, batch, kernel_impl="pallas_interpret")
-        assert tr.kernel_impl == "pallas_interpret"
-        assert tr.cfg.kernel_impl == "pallas_interpret"
-
     def test_registry_passes_kernel_impl(self, small):
         batch, cfg = small
         for name in ("FedGL", "local", "fedavg_fusion"):
-            tr = registry.build(name, cfg, batch,
-                                kernel_impl="pallas_interpret")
+            tr = registry.build(
+                name, dataclasses.replace(cfg, kernel_impl="pallas_interpret"), batch)
             assert isinstance(tr, FGLTrainer)
             assert tr.kernel_impl == "pallas_interpret"
 

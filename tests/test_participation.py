@@ -1,9 +1,10 @@
-"""Partial client participation: static-shape masks, mask-weighted
+"""Partial client participation: static-shape masks, weighted
 aggregation in all four Aggregators, and the engine threading.
 
-The contract: rho = 1 takes the exact unmasked code paths (fixed-seed
-histories bit-identical to pre-participation runs — the absolute pin being
-the goldens in ``tests/test_strategy_api.py``, which run at the default
+The contract: rho = 1 passes no weights, and the aggregators' unit weights
+fold away to the plain unweighted formulas (fixed-seed histories
+bit-identical to pre-participation runs — the absolute pin being the
+goldens in ``tests/test_strategy_api.py``, which run at the default
 ``participation=1.0``); rho < 1 samples a static [M] mask per round from a
 key stream that is a pure function of (cfg.seed, round), so checkpoints
 resume the participation schedule exactly.
@@ -57,8 +58,27 @@ class TestParticipationMask:
                 S.participation_mask(jax.random.key(0), 6, rho)
 
 
+def _unweighted_oracle(agg, params, adj, n, m_per):
+    """The aggregators' unweighted formulas in plain jnp: FedAvg's
+    ``sum(grouped, 1) / m_per``, Eq. 16's ``einsum(adj, client_sum)`` over
+    ``sum(adj, 0) * m_per``, and gossip's exchange of the plain server means."""
+    def server(leaf):
+        grouped = leaf.reshape((n, m_per) + leaf.shape[1:])
+        client_sum = jnp.sum(grouped, axis=1)
+        tail = (1,) * (leaf.ndim - 1)
+        if isinstance(agg, S.NeighborAggregator):
+            den = jnp.sum(adj, axis=0) * m_per
+            return jnp.einsum("rj,r...->j...", adj, client_sum) / den.reshape((n,) + tail)
+        mean = client_sum / m_per
+        if isinstance(agg, S.GossipAggregator):
+            mean = jnp.einsum("rj,r...->j...", adj, mean) / (
+                jnp.sum(adj, axis=0).reshape((n,) + tail))
+        return mean
+    return jax.tree.map(lambda leaf: jnp.repeat(server(leaf), m_per, axis=0), params)
+
+
 class TestMaskedAggregators:
-    """mask=None vs all-ones vs genuinely partial, per aggregator."""
+    """weights=None vs all-ones vs genuinely partial, per aggregator."""
 
     N, M_PER = 2, 3
 
@@ -74,20 +94,32 @@ class TestMaskedAggregators:
         S.FedAvgAggregator(), S.NeighborAggregator(),
         S.GossipAggregator(topology="adjacency", every_k=1)])
     def test_all_ones_mask_matches_unmasked(self, agg):
-        """Full-participation mask reproduces the mask=None path bitwise:
-        multiplying by 1.0 and dividing by the same count change nothing."""
+        """Unit weights and weights=None reproduce the unweighted formulas
+        bitwise: multiplying by 1.0 and dividing by the same count change
+        nothing. Compiled with weights=None, the unit weights and the
+        zero-weight fallback fold away to the oracle's own program."""
         params = self._params()
+        kw = dict(self._kw(jnp.asarray(ring_adjacency(self.N))), flag=1)
+        want = _unweighted_oracle(agg, params, kw["adj"], self.N, self.M_PER)
         ones = jnp.ones((self.N * self.M_PER,), jnp.float32)
-        out_none = agg.aggregate(params, **self._kw())
-        out_ones = agg.aggregate(params, mask=ones, **self._kw())
-        for a, b in zip(jax.tree.leaves(out_none), jax.tree.leaves(out_ones)):
-            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        got = {"none": agg.aggregate(params, **kw),
+               "ones": agg.aggregate(params, weights=ones, **kw)}
+        compiled = jax.jit(lambda p: agg.aggregate(p, **kw))(params)
+        oracle = jax.jit(lambda p: _unweighted_oracle(
+            agg, p, kw["adj"], self.N, self.M_PER))(params)
+        for a, b in zip(jax.tree.leaves(compiled), jax.tree.leaves(oracle)):
+            np.testing.assert_array_equal(np.asarray(a).view(np.uint32),
+                                          np.asarray(b).view(np.uint32))
+        for out in got.values():
+            for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(want)):
+                np.testing.assert_array_equal(np.asarray(a).view(np.uint32),
+                                              np.asarray(b).view(np.uint32))
 
     def test_fedavg_weighted_mean_preservation(self):
         """Per-server output is exactly the mean of participating clients."""
         params = self._params()
         mask = jnp.asarray([1, 0, 1, 0, 0, 1], jnp.float32)
-        out = S.FedAvgAggregator().aggregate(params, mask=mask, **self._kw())
+        out = S.FedAvgAggregator().aggregate(params, weights=mask, **self._kw())
         w = np.asarray(params["w"])
         np.testing.assert_allclose(np.asarray(out["w"])[0],
                                    (w[0] + w[2]) / 2, rtol=1e-6)
@@ -99,7 +131,7 @@ class TestMaskedAggregators:
     def test_fedavg_all_out_server_falls_back_to_plain_mean(self):
         params = self._params()
         mask = jnp.asarray([0, 0, 0, 1, 1, 0], jnp.float32)
-        out = S.FedAvgAggregator().aggregate(params, mask=mask, **self._kw())
+        out = S.FedAvgAggregator().aggregate(params, weights=mask, **self._kw())
         w = np.asarray(params["w"])
         np.testing.assert_allclose(np.asarray(out["w"])[0],
                                    w[:3].mean(axis=0), rtol=1e-6)
@@ -109,7 +141,7 @@ class TestMaskedAggregators:
         params = self._params()
         adj = jnp.asarray(ring_adjacency(2))  # N=2: all-to-all incl self
         mask = jnp.asarray([1, 1, 0, 1, 0, 0], jnp.float32)
-        out = S.NeighborAggregator().aggregate(params, mask=mask,
+        out = S.NeighborAggregator().aggregate(params, weights=mask,
                                                **self._kw(adj))
         w = np.asarray(params["w"])
         a = np.asarray(adj)
@@ -124,7 +156,7 @@ class TestMaskedAggregators:
     def test_identity_ignores_mask(self):
         params = self._params()
         mask = jnp.asarray([1, 0, 0, 0, 0, 0], jnp.float32)
-        out = S.IdentityAggregator().aggregate(params, mask=mask, **self._kw())
+        out = S.IdentityAggregator().aggregate(params, weights=mask, **self._kw())
         for a, b in zip(jax.tree.leaves(out), jax.tree.leaves(params)):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
@@ -134,8 +166,8 @@ class TestMaskedAggregators:
         params = self._params()
         mask = jnp.asarray([1, 0, 1, 0, 1, 1], jnp.float32)
         gossip = S.GossipAggregator(topology="adjacency", every_k=4)
-        out_g = gossip.aggregate(params, round=0, mask=mask, **self._kw())
-        out_f = S.FedAvgAggregator().aggregate(params, mask=mask, **self._kw())
+        out_g = gossip.aggregate(params, flag=0, weights=mask, **self._kw())
+        out_f = S.FedAvgAggregator().aggregate(params, weights=mask, **self._kw())
         for a, b in zip(jax.tree.leaves(out_g), jax.tree.leaves(out_f)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
 
@@ -146,7 +178,7 @@ class TestMaskedAggregators:
         adj = jnp.asarray(ring_adjacency(2))
         mask = jnp.asarray([1, 0, 0, 0, 1, 1], jnp.float32)
         gossip = S.GossipAggregator(topology="adjacency", every_k=1)
-        out = gossip.aggregate(params, round=0, mask=mask, **self._kw(adj))
+        out = gossip.aggregate(params, flag=1, weights=mask, **self._kw(adj))
         w = np.asarray(params["w"])
         means = np.stack([w[0], (w[4] + w[5]) / 2])   # masked server means
         want = (means[0] + means[1]) / 2              # N=2 all-to-all mix
@@ -165,12 +197,12 @@ class TestEngineThreading:
         _, h_def = tr_def.fit(jax.random.key(0), batch, rounds=3)
         _, h_one = tr_one.fit(jax.random.key(0), batch, rounds=3)
         assert h_def == h_one
-        assert tr_one._participation_mask(0) is None
+        assert tr_one._schedule(0) == (0, None)
 
     def test_rho_below_one_changes_training_and_stays_finite(self, small):
         batch, cfg = small
-        tr = registry.build("SpreadFGL", cfg, batch, num_servers=2,
-                            participation=0.5)
+        tr = registry.build("SpreadFGL", dataclasses.replace(cfg, participation=0.5),
+                            batch, num_servers=2)
         _, h_full = registry.build("SpreadFGL", cfg, batch, num_servers=2
                                    ).fit(jax.random.key(0), batch, rounds=3)
         _, h_half = tr.fit(jax.random.key(0), batch, rounds=3)
@@ -180,10 +212,11 @@ class TestEngineThreading:
     def test_mask_is_pure_function_of_round(self, small):
         """Same trainer, same round -> same mask; masks vary across rounds."""
         batch, cfg = small
-        tr = registry.build("FedGL", cfg, batch, participation=0.5)
-        m0a, m0b = tr._participation_mask(0), tr._participation_mask(0)
+        tr = registry.build("FedGL", dataclasses.replace(cfg, participation=0.5),
+                            batch)
+        m0a, m0b = tr._schedule(0)[1], tr._schedule(0)[1]
         np.testing.assert_array_equal(np.asarray(m0a), np.asarray(m0b))
-        masks = [np.asarray(tr._participation_mask(t)) for t in range(8)]
+        masks = [np.asarray(tr._schedule(t)[1]) for t in range(8)]
         assert any(np.any(masks[0] != m) for m in masks[1:])
         for m in masks:
             assert m.shape == (batch.num_clients,) and m.sum() == 2
@@ -205,26 +238,19 @@ class TestEngineThreading:
             np.testing.assert_allclose(first[k] + second[k], full[k],
                                        atol=1e-6)
 
-    def test_ctor_override_wins_over_cfg(self, small):
-        batch, cfg = small
-        tr = FGLTrainer(dataclasses.replace(cfg, participation=0.25), batch,
-                        participation=0.75)
-        assert tr.participation == 0.75
-        assert tr.cfg.participation == 0.75
-
     def test_rejects_out_of_range(self, small):
         batch, cfg = small
         for rho in (0.0, 1.5, -1.0):
             with pytest.raises(ValueError, match="participation"):
-                FGLTrainer(cfg, batch, participation=rho)
+                FGLTrainer(dataclasses.replace(cfg, participation=rho), batch)
 
     @pytest.mark.parametrize("name,kw", [
         ("local", {}), ("fedavg_fusion", {}), ("fedsage_plus", {}),
-        ("FedGL", {}), ("spreadfgl_gossip", {"num_servers": 2,
-                                             "gossip_every": 2})])
+        ("FedGL", {}), ("spreadfgl_gossip", {"num_servers": 2})])
     def test_every_registered_method_trains_under_partial(self, small, name,
                                                           kw):
         batch, cfg = small
-        tr = registry.build(name, cfg, batch, participation=0.5, **kw)
+        cfg = dataclasses.replace(cfg, participation=0.5, gossip_every=2)
+        tr = registry.build(name, cfg, batch, **kw)
         _, hist = tr.fit(jax.random.key(0), batch, rounds=2)
         assert np.isfinite(hist["loss"]).all(), name

@@ -154,8 +154,8 @@ def plain_run(tr: FGLTrainer, state, rounds):
         state = dataclasses.replace(state, params=params, opt_state=opt_state)
         state, _ = impute(state)
         state = dataclasses.replace(state, batch=strip(state.batch))
-        state.params = tr._agg_fn(state.params, round=tr._agg_phase(t),
-                                  mask=tr._agg_mask(t))
+        flag, weights = tr._schedule(t)
+        state.params = tr._agg_fn(state.params, flag=flag, weights=weights)
         read["loss"].append(loss_fn(state.params, state.batch))
         read["moment"].append((state.opt_state.mu, state.ae_opt.mu, state.as_opt.mu))
         read["weights"].append((state.params, state.ae_params, state.as_params))

@@ -248,16 +248,40 @@ class TestStrategies:
                                       np.repeat(np.arange(2), 3))
 
     def test_identity_aggregator_ignores_round_and_mask(self):
-        """Identity stays identity under every (round, mask) combination —
+        """Identity stays identity under every (flag, weights) combination —
         the `local` baseline must be untouched by participation or phase."""
         params = {"w": np.arange(12.0).reshape(6, 2)}
-        for round_, mask in [(0, None), (1, None),
-                             (0, np.asarray([1, 0, 1, 0, 1, 0], np.float32))]:
+        for flag, weights in [(0, None), (1, None),
+                              (0, np.asarray([1, 0, 1, 0, 1, 0], np.float32))]:
             out = S.IdentityAggregator().aggregate(
                 params, adj=np.eye(2, dtype=np.float32), num_servers=2,
-                m_per=3, round=round_, mask=mask)
+                m_per=3, flag=flag, weights=weights)
             np.testing.assert_array_equal(np.asarray(out["w"]),
                                           params["w"])
+
+    @pytest.mark.parametrize("agg", [
+        S.IdentityAggregator(), S.FedAvgAggregator(), S.NeighborAggregator(),
+        S.GossipAggregator(every_k=3),
+        S.AsyncAggregator(buffer_size=2, delay_dist="geometric",
+                          dropout_rate=0.2, seed=3)],
+        ids=lambda a: type(a).__name__)
+    def test_schedule_is_a_pure_function_of_the_round(self, agg):
+        """The same (settings, t) give the same (flag, weights) in any query
+        order, from a cold cache too; the flag is a static 0/1 and the
+        weights are None or a static [M] float vector."""
+        m, rounds = 6, 12
+        forward = [agg.schedule(t, m) for t in range(rounds)]
+        S._ASYNC_SCHEDULES.clear()
+        backward = {t: agg.schedule(t, m) for t in reversed(range(rounds))}
+        for t, (flag, weights) in enumerate(forward):
+            assert type(flag) is int and flag in (0, 1), (t, flag)
+            assert backward[t][0] == flag, t
+            if weights is None:
+                assert backward[t][1] is None, t
+            else:
+                assert weights.shape == (m,) and weights.dtype == np.float32
+                np.testing.assert_array_equal(np.asarray(backward[t][1]),
+                                              np.asarray(weights))
 
     def test_identity_aggregator_never_mixes(self, small):
         batch, cfg = small

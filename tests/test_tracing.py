@@ -120,7 +120,7 @@ def compiled(small):
     st = tr.init(jax.random.key(0), batch)
     return {
         "_local_fn": tr._local_fn.lower(st.params, st.opt_state, st.batch),
-        "_agg_fn": tr._agg_fn.lower(st.params, round=0, mask=None),
+        "_agg_fn": tr._agg_fn.lower(st.params, flag=0, weights=None),
         "_eval_fn": tr._eval_fn.lower(st.params, st.batch),
         "_impute_fn": tr._impute_fn.lower(st),
         "_propagate_fn": tr._propagate_fn.lower(st.batch),
