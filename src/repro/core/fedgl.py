@@ -195,11 +195,16 @@ class FGLTrainer:
         if edge_mesh is not None and self.n_servers % edge_mesh.size:
             raise ValueError(f"N={self.n_servers} servers must divide across the "
                              f"{edge_mesh.size}-device edge mesh")
-        self._local_fn = jax.jit(self._local_rounds)
+        # The classifiers and their optimizer state are donated: each output
+        # takes over an input's buffer, so a launch allocates none.
+        self._local_fn = jax.jit(self._local_rounds, donate_argnums=(0, 1))
         # The schedule's flag is a STATIC arg: at most 2 compiled variants
         # (exchange/skip, flush/skip), and skip rounds lower to no
         # cross-server collectives.
         self._agg_fn = jax.jit(self._aggregate, static_argnames=("flag",))
+        # step()'s own call: the classifiers (argument 0) are donated
+        self._agg_step_fn = jax.jit(self._aggregate, static_argnames=("flag",),
+                                    donate_argnums=(0,))
         self._impute_fn = _ForwardingJit(self._impute)
         # step()'s own call: the generator state and the batch's cache
         # (arguments 1 and 2) are donated
@@ -524,10 +529,13 @@ class FGLTrainer:
         (``{"round", "loss", "acc", "f1", "links"}``; ``links`` counts the
         imputed links written this round, 0 on a round without imputation)
         — callers decide when to sync. The caller's state object is never
-        mutated, but on an imputation round its generator state (``ae_params``,
-        ``ae_opt``, ``as_params``, ``as_opt``) and its batch's cache
-        (``batch.prop``) are donated to the new state's: those arrays of the
-        old state are deleted.
+        mutated, but its classifiers and their optimizer state (``params``,
+        ``opt_state``) are donated to the local and aggregation programs
+        every round, and on an imputation round its generator state
+        (``ae_params``, ``ae_opt``, ``as_params``, ``as_opt``) and its batch's
+        cache (``batch.prop``) to the imputation program: those arrays of the
+        old state are deleted. Read what you keep of a state before stepping
+        it (``fit`` rebinds ``state`` each round).
 
         Under ``jax.profiler`` the round is a ``fgl.round`` step span with
         one child span per dispatch (``fgl.local``, ``fgl.impute``,
@@ -555,7 +563,8 @@ class FGLTrainer:
             with span("fgl.schedule"):  # a pure function of the absolute round
                 flag, weights = self._schedule(t)
             with span("fgl.aggregate"):
-                state.params = self._agg_fn(state.params, flag=flag, weights=weights)
+                state.params = self._agg_step_fn(state.params, flag=flag,
+                                                 weights=weights)
             with span("fgl.evaluate"):
                 loss, acc, f1 = self._eval_fn(state.params, state.batch)
             state.round = t + 1

@@ -6,6 +6,7 @@ import os
 import tempfile
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -98,38 +99,84 @@ class TestHistoryRegression:
         assert hist["links"][0] > 0 and hist["links"][1] == 0
 
     def test_step_does_not_mutate_input_state(self, small):
+        """step() never rebinds a field of the caller's state object, and it
+        donates the caller's classifiers and their optimizer state to the
+        new state's: those arrays of the old state are deleted."""
         batch, cfg = small
         tr = make_spreadfgl(cfg, batch, num_servers=2)
         state = tr.init(jax.random.key(0), batch)
-        before = jax.tree.map(np.asarray, state.params)
-        _, _ = tr.step(state)
-        assert state.round == 0
-        for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(state.params)):
-            np.testing.assert_array_equal(a, np.asarray(b))
+        fields = {f.name: getattr(state, f.name) for f in dataclasses.fields(state)}
+        new, _ = tr.step(state)
+        assert state.round == 0 and new.round == 1
+        for name, value in fields.items():
+            assert getattr(state, name) is value, name
+        assert all(leaf.is_deleted()
+                   for leaf in jax.tree.leaves((state.params, state.opt_state)))
+        assert not any(leaf.is_deleted()
+                       for leaf in jax.tree.leaves((new.params, new.opt_state)))
 
     def test_an_imputation_round_takes_over_the_generator_state(self, small):
         """On an imputation round step() donates the old state's generator
-        state and its batch's cache to the new one, and computes what the
-        undonated program does; the classifiers and the batch's own fields
-        stay the caller's."""
+        state and its batch's cache to the new one, as it donates the
+        classifiers every round, and computes what the undonated programs
+        do; the batch's own fields stay the caller's."""
         batch, cfg = small
         tr = make_spreadfgl(cfg, batch, num_servers=2)
         state = tr.init(jax.random.key(0), batch)
         want, _ = jax.jit(tr._impute)(dataclasses.replace(
-            state, params=tr._local_fn(state.params, state.opt_state, state.batch)[0]))
+            state, params=jax.jit(tr._local_rounds)(
+                state.params, state.opt_state, state.batch)[0]))
         new, m = tr.step(state)
         assert int(m["links"]) > 0
-        donated = (state.ae_params, state.ae_opt, state.as_params, state.as_opt,
-                   state.batch.prop)
+        donated = (state.params, state.opt_state, state.ae_params, state.ae_opt,
+                   state.as_params, state.as_opt, state.batch.prop)
         assert all(leaf.is_deleted() for leaf in jax.tree.leaves(donated))
         assert not any(leaf.is_deleted() for leaf in jax.tree.leaves(
-            (state.params, state.opt_state, state.batch.replace(prop=None), new))
-            if isinstance(leaf, jax.Array))
+            (state.batch.replace(prop=None), new)) if isinstance(leaf, jax.Array))
         for a, b in zip(jax.tree.leaves((new.ae_params, new.ae_opt, new.as_params,
                                          new.as_opt, new.batch)),
                         jax.tree.leaves((want.ae_params, want.ae_opt, want.as_params,
                                          want.as_opt, want.batch))):
             np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    @pytest.mark.filterwarnings("error:Some donated buffers were not usable")
+    @pytest.mark.parametrize("name,kw", [("fedavg_fusion", {}),
+                                         ("SpreadFGL", {"num_servers": 2})])
+    def test_donating_steps_equal_undonated_programs(self, small, name, kw):
+        """Three step() rounds, which donate the classifier state to the
+        local and aggregation programs, equal to the bit a loop of the same
+        programs jitted without donation; every round deletes the old
+        state's classifiers and optimizer state, and every donation is
+        usable."""
+        batch, cfg = small
+        tr = registry.build(name, cfg, batch, **kw)
+        key = jax.random.key(0)
+        local, impute = jax.jit(tr._local_rounds), jax.jit(tr._impute)
+        state = tr.init(key, batch)
+        want = []
+        for t in range(3):
+            params, opt_state = local(state.params, state.opt_state, state.batch)
+            state = dataclasses.replace(state, params=params, opt_state=opt_state)
+            if tr.imputation.active and t % tr.cfg.imputation_interval == 0:
+                state, _ = impute(state)
+            flag, weights = tr._schedule(t)
+            state.params = tr._agg_fn(state.params, flag=flag, weights=weights)
+            want.append(jax.device_get((state.params, state.opt_state,
+                                        tr._eval_fn(state.params, state.batch))))
+        state = tr.init(key, batch)
+        got = []
+        for _ in range(3):
+            old = state
+            state, m = tr.step(state)
+            assert all(leaf.is_deleted()
+                       for leaf in jax.tree.leaves((old.params, old.opt_state)))
+            # copied on the device: a host view of a CPU array would hold
+            # its buffer, and the next step could not take it over
+            got.append(jax.tree.map(jnp.copy, (state.params, state.opt_state,
+                                               (m["loss"], m["acc"], m["f1"]))))
+        got = jax.device_get(got)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(a, b)
 
 
 class TestResume:

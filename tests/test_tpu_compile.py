@@ -31,12 +31,16 @@ Table-I graphs at full size, label-propagation partition, 12 aug slots):
   the local and evaluation programs run the kernel once (layer 2), and the
   imputation program twice (layer 2 of its embeddings, and layer 1 of the
   patched graphs as it fills the cache anew).
+- the round's local and aggregation programs as ``step`` calls them, which
+  donate the classifier state: every output of each takes over a donated
+  input's buffer (``input_output_alias``), so a launch allocates none.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -167,3 +171,32 @@ def test_the_rounds_read_layer_1_from_the_batch_cache(cora_n3m6, program, module
     assert text.startswith(f"HloModule {module}")
     sage = [line for line in _kernel_calls(text) if "sage" in line]
     assert len(sage) == calls, sage
+
+
+def _aliased_outputs(text):
+    """The entries of the compiled module's ``input_output_alias``: the
+    outputs that take over a donated input's buffer."""
+    header = text.split("\n", 1)[0]
+    alias = header.partition("input_output_alias={")[2]
+    return re.findall(r"\{[\d,\s]*\}:\s*\(", alias)
+
+
+@pytest.mark.parametrize("program,module", [
+    ("_local_fn", "jit__local_rounds"),
+    ("_agg_step_fn", "jit__aggregate"),
+])
+def test_the_round_programs_alias_every_output(cora_n3m6, program, module):
+    """step()'s local and aggregation programs take over the classifier
+    state they are given: every output of each reuses a donated input's
+    buffer, so a launch allocates no output."""
+    tr, state = cora_n3m6
+    flag, weights = tr._schedule(0)
+    args, kwargs = {"_local_fn": ((state.params, state.opt_state, state.batch), {}),
+                    "_agg_step_fn": ((state.params,), {"flag": flag, "weights": weights}),
+                    }[program]
+    lowered = getattr(tr, program).lower(*args, **kwargs)
+    text = lowered.compile().as_text()
+    assert text.startswith(f"HloModule {module}")
+    n_out = len(jax.tree.leaves(lowered.out_info))
+    assert n_out == {"_local_fn": 19, "_agg_step_fn": 6}[program]
+    assert len(_aliased_outputs(text)) == n_out
